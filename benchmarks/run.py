@@ -56,8 +56,8 @@ def run_multidevice(script: str, n_devices: int, sentinel: str,
     import os
     import subprocess
     import sys
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    from repro.launch.mesh import cpu_child_env
+    env = dict(os.environ, **cpu_child_env(n_devices))
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, timeout=timeout, env=env)
     if proc.returncode != 0 or sentinel not in proc.stdout:
@@ -358,6 +358,7 @@ from repro.models import build_model
 from repro.perf.hlo_cost import analyze_hlo
 from repro.train import Hyper, make_loss_fn
 from repro.train.tensor_parallel import make_tp_loss_fn
+from repro.launch.mesh import make_mesh
 
 fams = {
     "dense": ModelConfig("btp", Family.DENSE, n_layers=2, d_model=128,
@@ -375,7 +376,7 @@ fams = {
                                         chunk=32)),
 }
 shape = InputShape("b", 64, 8, "train")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 n_dev = 4
 for fam, cfg in fams.items():
     ds = SyntheticDataset(cfg, shape)
@@ -452,9 +453,10 @@ from repro.train import Hyper, make_loss_fn
 from repro.train import executor as exlib
 from repro.train.executor import make_executor_loss_fn
 from repro.train.tensor_parallel import RingCtx
+from repro.launch.mesh import make_mesh
 
 CP = 2
-mesh = jax.make_mesh((CP,), ("cp",))
+mesh = make_mesh((CP,), ("cp",))
 cfg = ModelConfig("bcp", Family.DENSE, n_layers=2, d_model=128, n_heads=2,
                   n_kv_heads=2, d_ff=256, vocab=512)
 rng = np.random.default_rng(0)
@@ -592,9 +594,10 @@ from repro.models import build_model
 from repro.perf.hlo_cost import analyze_hlo
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 
 EP = 2
-mesh = jax.make_mesh((2, EP), ("data", "model"))
+mesh = make_mesh((2, EP), ("data", "model"))
 shape = InputShape("bep", 512, 4, "train")
 toks = shape.global_batch * shape.seq_len
 
@@ -732,10 +735,11 @@ from repro.checkpoint import CheckpointManager
 from repro.launch.mesh import shrink_mesh
 from repro.models import build_model
 from repro.train import init_train_state
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig("b", Family.DENSE, n_layers=4, d_model=512, n_heads=8,
                   n_kv_heads=8, d_ff=2048, vocab=8192)
 plan = ParallelPlan(remat="none", compute_dtype="float32", zero_stage=1)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 model = build_model(cfg, plan, mesh, ("data",))
 state = init_train_state(model, jax.random.PRNGKey(0), mesh=mesh, plan=plan)
 nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
@@ -975,10 +979,11 @@ from repro.ft import (Monitor, RemeshSpec, StragglerDetector, StragglerTimer,
 from repro.ft.inject import FaultSpec, armed
 from repro.models import build_model
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("bench", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2,
                     microbatches=4)
 SEQ, BATCH = 32, 8
@@ -1182,12 +1187,13 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.tensor_parallel import make_tp_loss_fn
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig("q", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
 shape = InputShape("q", 16, 4, "train")
 ds = SyntheticDataset(cfg, shape)
 batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", tp=2,
                     tp_impl="overlap")
 model = build_model(cfg, plan)
@@ -1215,12 +1221,13 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig("q", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
 shape = InputShape("q", 16, 4, "train")
 ds = SyntheticDataset(cfg, shape)
 batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((1, 2), ("data", "cp"))
+mesh = make_mesh((1, 2), ("data", "cp"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2,
                     cp_impl="ring")
 model = build_model(cfg, plan)
@@ -1248,6 +1255,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig("q", Family.MOE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=0, vocab=128,
                   moe=MoEConfig(num_experts=4, top_k=2, d_expert=64,
@@ -1255,7 +1263,7 @@ cfg = ModelConfig("q", Family.MOE, n_layers=2, d_model=64, n_heads=4,
 shape = InputShape("q", 16, 4, "train")
 ds = SyntheticDataset(cfg, shape)
 batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", ep=2,
                     ep_impl="overlap")
 model = build_model(cfg, plan)
@@ -1288,13 +1296,14 @@ from repro.ft import Monitor, RemeshSpec, run_with_recovery
 from repro.launch.mesh import shrink_mesh
 from repro.models import build_model
 from repro.train import Hyper, init_train_state, make_train_step
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig("q", Family.DENSE, n_layers=2, d_model=32, n_heads=2,
                   n_kv_heads=2, d_ff=64, vocab=64)
 plan = ParallelPlan(remat="none", compute_dtype="float32", zero_stage=1)
 hyper = Hyper(peak_lr=1e-3, total_steps=20, z_loss=0.0)
 ds = SyntheticDataset(cfg, InputShape("q", 16, 8, "train"))
 get_batch = lambda s: {k: jnp.asarray(v) for k, v in ds.batch(s).items()}
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 model = build_model(cfg, plan, mesh, ("data",))
 state0 = init_train_state(model, jax.random.PRNGKey(0), mesh=mesh, plan=plan)
 step_big = jax.jit(make_train_step(model, plan, hyper, mesh=mesh))
@@ -1353,10 +1362,11 @@ from repro.ft import (Monitor, RemeshSpec, StragglerDetector, StragglerTimer,
 from repro.ft.inject import FaultSpec, armed
 from repro.models import build_model
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("q", Family.DENSE, n_layers=4, d_model=32, n_heads=2,
                   n_kv_heads=2, d_ff=64, vocab=64)
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2,
                     microbatches=4)
 ds = SyntheticDataset(cfg, InputShape("q", 16, 8, "train"))

@@ -27,6 +27,7 @@ import jax.numpy as jnp                                 # noqa: E402
 
 from repro.core import Family, InputShape, ModelConfig, ParallelPlan  # noqa: E402
 from repro.data import SyntheticDataset                 # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.models import build_model                    # noqa: E402
 from repro.optim import adamw_init, adamw_update, clip_by_global_norm  # noqa: E402
 from repro.train import Hyper, make_loss_fn             # noqa: E402
@@ -34,7 +35,7 @@ from repro.train.pipeline import pipelined_loss_fn      # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = ModelConfig("pipe-demo", Family.DENSE, n_layers=4, d_model=128,
                       n_heads=4, n_kv_heads=2, d_ff=256, vocab=512)
     # tp_impl pinned so the baseline stays the GSPMD pipeline even on TPU
@@ -104,7 +105,7 @@ def main():
     # materializes full-context K/V or scores. At real long-context sizes
     # (train/executor.py: plan.cp=8, S=512k) this is what keeps attention
     # activation memory, the long-S bottleneck, flat per device.
-    cp_mesh = jax.make_mesh((2, 2, 2), ("pod", "cp", "model"))
+    cp_mesh = make_mesh((2, 2, 2), ("pod", "cp", "model"))
     cp_plan = dataclasses.replace(plan, tp=2, tp_impl="overlap",
                                   cp=2, cp_impl="ring")
     cp_loss_fn = pipelined_loss_fn(cfg, cp_plan, cp_mesh, ())

@@ -22,11 +22,12 @@ import numpy as np                                      # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import ParallelPlan, get_smoke_config, sharding  # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.models import build_model                    # noqa: E402
 
 
 def serve(arch: str, max_ctx: int = 256, gen: int = 32):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_smoke_config(arch)
     if cfg.sliding_window:
         cfg = dataclasses.replace(cfg, sliding_window=64, long_context=True)

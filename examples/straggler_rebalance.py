@@ -40,12 +40,13 @@ from repro.data import SyntheticDataset                 # noqa: E402
 from repro.ft import (Monitor, RemeshSpec, StragglerDetector,  # noqa: E402
                       StragglerTimer, run_with_recovery)
 from repro.ft.inject import FaultSpec, armed            # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.models import build_model                    # noqa: E402
 from repro.train.pipeline import pipelined_loss_fn      # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((2, 2), ("pod", "data"))
+    mesh = make_mesh((2, 2), ("pod", "data"))
     cfg = ModelConfig("slow-demo", Family.DENSE, n_layers=4, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab=256)
     plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2,

@@ -32,6 +32,7 @@ import jax.numpy as jnp                                 # noqa: E402
 from repro.core import InputShape, ParallelPlan, get_smoke_config  # noqa: E402
 from repro.core.sharding import ep_spec_for_param       # noqa: E402
 from repro.data import SyntheticDataset                 # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.models import build_model                    # noqa: E402
 from repro.train import Hyper, init_train_state, make_train_step  # noqa: E402
 from repro.train.executor import make_executor_loss_fn  # noqa: E402
@@ -47,7 +48,7 @@ def main():
     e = cfg.moe.num_experts
 
     # --- ep-only: experts over the model axis, overlapped a2a ring ---------
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = ParallelPlan(ep=4, ep_impl="overlap", zero_stage=1,
                         remat="selective", compute_dtype="float32")
     shape = InputShape("moe-ep", seq_len=64, global_batch=8, kind="train")
@@ -77,7 +78,7 @@ def main():
     # Attention runs as a zigzag cp ring over "cp" with overlap-TP rings over
     # "model"; the MoE sublayer re-reads those same cp x model devices as one
     # flat expert axis. Overlap and blocking a2a are the same math.
-    fold_mesh = jax.make_mesh((2, 2, 2), ("data", "cp", "model"))
+    fold_mesh = make_mesh((2, 2, 2), ("data", "cp", "model"))
     # host copies: the trained params are committed to the ep-only mesh
     params = jax.device_get(state.params)
     losses = {}

@@ -87,9 +87,14 @@ class MemoryCheckpointTier:
         Builds the same manifest the disk tier would (per-shard key, global
         index slices, sha256-prefix, CRC32, dtype/shape) plus a ``home``
         group per shard, then rotates each group's buffers onto its ring
-        neighbor's mirror. The ring's ``maxlen`` evicts the oldest entry.
+        neighbor's mirror. The oldest entry is evicted *before* the new one
+        is built, so host RAM never holds ``keep + 1`` snapshots: at full
+        model size that extra copy (twice the state with mirrors) is what
+        exhausts a host's RAM.
         """
         t0 = time.time()
+        while len(self._ring) >= self.keep:
+            self._ring.popleft()
         named = _flatten_with_names(tree)
         primary: Dict[int, Dict[str, np.ndarray]] = \
             {g: {} for g in range(self.groups)}

@@ -366,7 +366,8 @@ class CheckpointManager:
             self.persist_seconds = time.time() - t1
             if self.flight is not None:
                 self.flight.record("ckpt.persist", step, tier="disk",
-                                   seconds=self.persist_seconds)
+                                   seconds=self.persist_seconds,
+                                   snapshot_seconds=self.snapshot_seconds)
             self._gc()
 
         def _bg():

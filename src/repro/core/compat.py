@@ -1,34 +1,18 @@
-"""Compatibility shims for jax API drift across the supported version range.
+"""The one ``shard_map`` entry point.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed its replication-check kwarg (``check_rep`` -> ``check_vma``) along
-the way. Every call site in this repo goes through :func:`shard_map` so the
-rest of the codebase is version-agnostic.
+Every call site goes through :func:`shard_map` so the replication check is
+set in one place.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the top-level promotion and the kwarg rename (check_rep -> check_vma) were
-# separate jax changes — key off the resolved signature, not the import path
-try:
-    _PARAMS = inspect.signature(_shard_map).parameters
-except (TypeError, ValueError):  # signature not introspectable
-    _PARAMS = {}
-_CHECK_KW = "check_vma" if "check_vma" in _PARAMS else "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    # The replication check stays off in both eras: 0.4.x's check_rep has no
-    # rule for the `name` (checkpoint_name) primitive, and 0.6+'s check_vma
-    # is stricter than these specs are annotated for. With the check off,
-    # grad-of-shard_map additionally requires scan carries to be non-scalar
-    # (see train/pipeline.py) — scalar residuals can't be spec'd per-device.
+    # The replication check stays off: check_vma is stricter than these specs
+    # are annotated for. With the check off, grad-of-shard_map additionally
+    # requires scan carries to be non-scalar (see train/pipeline.py) — scalar
+    # residuals can't be spec'd per-device.
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+                      check_vma=False)

@@ -275,6 +275,10 @@ def warn_shard_local_routing(cfg: "ModelConfig") -> None:
         "equivalence", UserWarning, stacklevel=3)
 
 
+# the ParallelPlan fields that pick a fused kernel or its XLA twin
+KERNEL_KNOBS = ("attn_impl", "moe_gemm_impl", "ssm_impl")
+
+
 @dataclasses.dataclass(frozen=True)
 class ParallelPlan:
     """Distribution strategy per survey §4.
@@ -463,7 +467,7 @@ class ParallelPlan:
         if self.integrity not in ("off", "audit"):
             raise ValueError(
                 f"integrity must be off|audit, got {self.integrity!r}")
-        for knob in ("attn_impl", "moe_gemm_impl", "ssm_impl"):
+        for knob in KERNEL_KNOBS:
             if getattr(self, knob) not in ("auto", "xla", "pallas"):
                 raise ValueError(
                     f"{knob} must be auto|xla|pallas, got {getattr(self, knob)!r}")

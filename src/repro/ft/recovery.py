@@ -96,6 +96,8 @@ import time
 from contextlib import nullcontext
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
+import jax
+
 from repro.checkpoint.store import CheckpointManager, CorruptCheckpointError
 from repro.core.config import RecoveryPolicy
 from . import inject as _inject
@@ -158,6 +160,9 @@ class RunReport:
     preempt_step: Optional[int] = None
     # where the flight recorder dumped its JSON (preemption/crash), if at all
     flight_path: Optional[str] = None
+    # wall seconds of every step executed, in order (replays and rejected
+    # steps included), each ending when the new state and metrics are ready
+    step_seconds: List[float] = dataclasses.field(default_factory=list)
 
 
 def run_with_recovery(
@@ -259,6 +264,7 @@ def run_with_recovery(
             and getattr(straggler.detector, "flight", None) is None:
         straggler.detector.flight = flight
     losses: List[float] = []
+    step_times: List[float] = []
     actions: List[Tuple[int, str, str]] = []
     restores = 0
     remeshes = 0
@@ -352,7 +358,8 @@ def run_with_recovery(
         base = dict(steps_done=step, anomalies=monitor.anomalies,
                     restores=restores, losses=losses, remeshes=remeshes,
                     rebalances=rebalances, actions=actions,
-                    ckpt_fallbacks=fallbacks, mem_restores=mem_restores)
+                    ckpt_fallbacks=fallbacks, mem_restores=mem_restores,
+                    step_seconds=step_times)
         base.update(over)
         return RunReport(**base)
 
@@ -420,10 +427,11 @@ def run_with_recovery(
             with _sect("data.fetch", step):
                 batch = get_batch(step)
             t0 = time.perf_counter()
-            new_state, metrics = fn(cur, batch)
-            loss = float(metrics["loss"])    # blocks on the device, so the
-            gnorm = float(metrics.get("grad_norm", 0.0))  # timing below is
-            step_seconds = time.perf_counter() - t0       # real step time
+            new_state, metrics = jax.block_until_ready(fn(cur, batch))
+            step_seconds = time.perf_counter() - t0
+            step_times.append(step_seconds)
+            loss = float(metrics["loss"])
+            gnorm = float(metrics.get("grad_norm", 0.0))
             div = float(metrics.get("integrity_div", 0.0))
             if flight is not None:
                 for point, kind, fstep in \

@@ -20,12 +20,14 @@ dispatcher with the matching :class:`~repro.core.config.ParallelPlan` knob —
 
 - ``"xla"``    — the pure-jnp twins (models/layers.py attention,
   masked einsum, models/ssm.py ssd_scan); kept as the gradient oracles.
-- ``"pallas"`` — the fused kernel (interpret mode off-TPU); falls back to XLA
-  only when hard preconditions fail (traced mask params, SSD initial state).
-- ``"auto"``   — pallas only on TPU backends; XLA everywhere else.
+- ``"pallas"`` — the fused kernel (interpret mode off-TPU); raises when hard
+  preconditions fail (traced mask params, SSD initial state).
+- ``"auto"``   — pallas only on TPU backends (where its preconditions hold);
+  XLA everywhere else.
 
 Each kernel has a pure-jnp oracle in ref.py and a jit'd wrapper in ops.py;
-tests sweep shapes/dtypes/grads and assert allclose in interpret mode.
+tests sweep shapes/dtypes/grads and assert allclose in interpret mode, and
+tests/test_tpu_compile.py compiles each one for a described TPU v5e.
 """
 
 from .dispatch import (

@@ -9,12 +9,16 @@ matching ``ParallelPlan`` knob (``attn_impl`` / ``moe_gemm_impl`` /
 Pallas kernel or the XLA twin runs. Shared rules (:func:`_resolve_choice`):
 
 - ``impl="xla"``    — always the pure-XLA twin (also the gradient oracle).
-- ``impl="pallas"`` — the fused kernel whenever its static preconditions hold
-  (attention: compile-time mask params; SSD: no initial state); XLA otherwise.
+- ``impl="pallas"`` — the fused kernel; a call that breaks its static
+  preconditions (attention: compile-time mask params; SSD: no initial state)
+  raises ``ValueError`` instead of quietly running XLA.
 - ``impl="auto"``   — Pallas iff running on a TPU backend and the
   preconditions hold. Off-TPU the Pallas interpreter validates correctness
   but is orders of magnitude slower, so auto never selects it — tests and
-  benchmarks opt in with ``impl="pallas"``.
+  benchmarks opt in with ``impl="pallas"``. The GSPMD model path on a
+  mesh of several devices hands ``"xla"`` in place of ``"auto"``
+  (``models.families.gspmd_kernel_plan``): GSPMD cannot partition a
+  Mosaic kernel, while the shard_map paths call the kernels per shard.
 
 Every fused kernel here is differentiable (``jax.custom_vjp`` recompute
 backwards), so the dispatchers sit on the training path, not just prefill.
@@ -72,16 +76,20 @@ def _is_static(x) -> bool:
 
 
 def _resolve_choice(impl: str, *, knob: str, explicit_ok: bool,
-                    auto_ok: bool) -> str:
+                    auto_ok: bool, why: str = "") -> str:
     """Shared auto|xla|pallas resolution. ``explicit_ok`` gates an explicit
-    ``"pallas"`` request (hard preconditions); ``auto_ok`` additionally gates
-    ``"auto"`` (soft preferences like lane-friendly shapes)."""
+    ``"pallas"`` request (hard preconditions; ``why`` names them); an
+    unmet one raises rather than running XLA in the kernel's place.
+    ``auto_ok`` additionally gates ``"auto"`` (soft preferences like
+    lane-friendly shapes)."""
     if impl not in IMPLS:
         raise ValueError(f"{knob} must be one of {IMPLS}, got {impl!r}")
     if impl == "xla":
         return "xla"
     if impl == "pallas":
-        return "pallas" if explicit_ok else "xla"
+        if not explicit_ok:
+            raise ValueError(f"{knob}='pallas' cannot run here: {why}")
+        return "pallas"
     if explicit_ok and auto_ok and jax.default_backend() == "tpu":
         return "pallas"
     return "xla"
@@ -94,7 +102,8 @@ def select_impl(impl: str, *, head_dim: int, window, q_offset) -> str:
     static = _is_static(window) and _is_static(q_offset)
     return _resolve_choice(
         impl, knob="attn_impl", explicit_ok=static,
-        auto_ok=head_dim % 8 == 0 and head_dim <= 256)
+        auto_ok=head_dim % 8 == 0 and head_dim <= 256,
+        why="the kernel's mask needs a static window and q_offset")
 
 
 TP_IMPLS = ("auto", "gspmd", "overlap")
@@ -257,9 +266,10 @@ def select_gemm_impl(impl: str) -> str:
 
 def select_ssd_impl(impl: str, *, has_initial_state: bool = False) -> str:
     """Resolve the SSD impl. The fused kernel starts from a zero state, so a
-    caller-supplied initial state falls back to the XLA scan."""
+    caller-supplied initial state takes the XLA scan under ``auto``."""
     return _resolve_choice(impl, knob="ssm_impl",
-                           explicit_ok=not has_initial_state, auto_ok=True)
+                           explicit_ok=not has_initial_state, auto_ok=True,
+                           why="the kernel starts from a zero state")
 
 
 # ---------------------------------------------------------------------------

@@ -141,7 +141,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _fwd_scratch(block_q: int, hd: int):
@@ -196,17 +196,18 @@ def _flash_forward(q, k, v, causal, window, softcap, scale, q_offset,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda bi, h, qi, ki: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, h, qi, ki: (bi, h, qi)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda bi, h, qi, ki: (bi, h, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s_pad, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, s_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1, s_pad), jnp.float32),
         ],
         scratch_shapes=_fwd_scratch(block_q, hd),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
-    return out[:, :, :s, :], lse[:, :, :s]
+    return out[:, :, :s, :], lse[:, :, 0, :s]
 
 
 # ---------------------------------------------------------------------------
@@ -265,8 +266,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, acc_ref,
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           q_offset=q_offset, block_q=block_q, block_k=block_k,
                           seq_q=seq_q, seq_k=seq_k)
-        _, ds = _recompute_ds(q, k, v, do, lse_ref[0, 0], dl_ref[0, 0], mask,
-                              scale=scale, softcap=softcap)
+        _, ds = _recompute_ds(q, k, v, do, lse_ref[0, 0, 0], dl_ref[0, 0, 0],
+                              mask, scale=scale, softcap=softcap)
         acc_ref[...] += jax.lax.dot(
             ds, k, preferred_element_type=jnp.float32) * scale
 
@@ -303,8 +304,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           q_offset=q_offset, block_q=block_q, block_k=block_k,
                           seq_q=seq_q, seq_k=seq_k)
-        p, ds = _recompute_ds(q, k, v, do, lse_ref[0, 0], dl_ref[0, 0], mask,
-                              scale=scale, softcap=softcap)
+        p, ds = _recompute_ds(q, k, v, do, lse_ref[0, 0, 0], dl_ref[0, 0, 0],
+                              mask, scale=scale, softcap=softcap)
         # contract the query dim: pᵀ·do and dsᵀ·q without explicit transposes
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -355,8 +356,11 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal, window, softcap,
     t_pad = -(-t // block_k) * block_k
     qp = _pad_seq(q, 2, s_pad)
     dop = _pad_seq(do, 2, s_pad)
-    lsep = _pad_seq(lse, 2, s_pad)
-    deltap = _pad_seq(delta, 2, s_pad)
+    # per-row statistics travel as (B, Hq, 1, S): a (1, block_q) block is a
+    # lane-major row the TPU tiling accepts, where (1, 1, block_q) over
+    # (B, Hq, S) puts a 1 in the sublane dim
+    lsep = _pad_seq(lse, 2, s_pad)[:, :, None, :]
+    deltap = _pad_seq(delta, 2, s_pad)[:, :, None, :]
     kp = _pad_seq(k, 2, t_pad)
     vp = _pad_seq(v, 2, t_pad)
 
@@ -369,7 +373,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal, window, softcap,
                           lambda bi, h, i, j: (bi, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, hd),
                            lambda bi, h, i, j, g=group: (bi, h // g, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda bi, h, i, j: (bi, h, i))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda bi, h, i, j: (bi, h, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kwargs),
@@ -380,6 +385,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal, window, softcap,
         out_shape=jax.ShapeDtypeStruct((b, hq, s_pad, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     # dk/dv grids put the query-tile dim minor so the accumulators carry; the
@@ -388,7 +394,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal, window, softcap,
                             lambda bi, h, i, j: (bi, h, j, 0))
     kv_spec_t = pl.BlockSpec((1, 1, block_k, hd),
                              lambda bi, h, i, j, g=group: (bi, h // g, i, 0))
-    row_spec_t = pl.BlockSpec((1, 1, block_q), lambda bi, h, i, j: (bi, h, j))
+    row_spec_t = pl.BlockSpec((1, 1, 1, block_q),
+                              lambda bi, h, i, j: (bi, h, 0, j))
     dkv_out = pl.BlockSpec((1, 1, block_k, hd),
                            lambda bi, h, i, j: (bi, h, i, 0))
 
@@ -403,6 +410,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal, window, softcap,
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
 
     # GQA: gradients were emitted per query head; sum each group back onto

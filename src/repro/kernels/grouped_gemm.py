@@ -49,6 +49,7 @@ MASK_MODES = ("rows", "contract")
 
 def _kernel(gs_ref, x_ref, w_ref, o_ref, acc_ref, *, n_dsteps: int,
             block_r: int, block_k: int, mask: str):
+    ei = pl.program_id(0)
     ri = pl.program_id(1)
     di = pl.program_id(3)
 
@@ -56,7 +57,7 @@ def _kernel(gs_ref, x_ref, w_ref, o_ref, acc_ref, *, n_dsteps: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    gs = gs_ref[0]
+    gs = gs_ref[ei]   # scalar-prefetched group sizes live in SMEM
     # whole-tile skip: row tiles past the expert's load ("rows") or contraction
     # tiles made of padding rows ("contract") contribute nothing
     relevant = (ri * block_r < gs) if mask == "rows" else (di * block_k < gs)
@@ -108,22 +109,28 @@ def _grouped_gemm(x, w, gs, *, mask: str, block_r: int, block_co: int,
     rp, kp, fp = xp.shape[1], xp.shape[2], wp.shape[2]
     grid = (e, rp // block_r, fp // block_co, kp // block_k)
 
+    # group_sizes ride scalar prefetch: the whole (E,) vector sits in SMEM
+    # and every grid step reads its expert's entry (a (1,) VMEM block per
+    # expert is not a tiling the TPU accepts)
     out = pl.pallas_call(
         functools.partial(_kernel, n_dsteps=grid[3], block_r=block_r,
                           block_k=block_k, mask=mask),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda ei, ri, fi, di: (ei,)),
-            pl.BlockSpec((1, block_r, block_k),
-                         lambda ei, ri, fi, di: (ei, ri, di)),
-            pl.BlockSpec((1, block_k, block_co),
-                         lambda ei, ri, fi, di: (ei, di, fi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_r, block_co),
-                               lambda ei, ri, fi, di: (ei, ri, fi)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_r, block_k),
+                             lambda ei, ri, fi, di, gs_ref: (ei, ri, di)),
+                pl.BlockSpec((1, block_k, block_co),
+                             lambda ei, ri, fi, di, gs_ref: (ei, di, fi)),
+            ],
+            out_specs=pl.BlockSpec((1, block_r, block_co),
+                                   lambda ei, ri, fi, di, gs_ref: (ei, ri, fi)),
+            scratch_shapes=[pltpu.VMEM((block_r, block_co), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((e, rp, fp), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_r, block_co), jnp.float32)],
         interpret=interpret,
+        name=f"grouped_gemm_{mask}",
     )(gs, xp, wp)
     return out[:, :r, :f]
 

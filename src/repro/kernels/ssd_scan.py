@@ -57,19 +57,39 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import resolve_interpret
 
+# Per-step vectors (dt, ddt, dda) travel as (B, H, 1, L): a (1, chunk) block
+# is a lane-major row the TPU tiling accepts. The per-head decay rate A is a
+# scalar per grid step, so the whole (H,) vector sits in SMEM.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _lower_tri(q: int, upper: bool = False):
+    """(q, q) mask of i >= j (``upper``: i <= j)."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    return i <= j if upper else i >= j
+
+
+def _cumsum(v, reverse: bool = False):
+    """Inclusive prefix (``reverse``: suffix) sum of a (q,) vector as a masked
+    row reduction: the TPU kernel lowering has no cumsum primitive."""
+    tri = _lower_tri(v.shape[0], upper=reverse)
+    return jnp.where(tri, v[None, :], 0.0).sum(axis=1)
+
 
 def _chunk_decay(dt, a):
-    """Shared per-chunk decay math: (da, cs, L) with L strictly in registers/VMEM."""
+    """Shared per-chunk decay math: (cs, cs_end, L) with L strictly in
+    registers/VMEM. ``cs_end`` is cs[-1], taken as a sum because the kernel
+    lowering has no dynamic slice."""
     da = dt * a                                   # (q,) log-decays
-    cs = jnp.cumsum(da)                           # (q,)
+    cs = _cumsum(da)                              # (q,)
     q = cs.shape[0]
     li = cs[:, None] - cs[None, :]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-           >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+    tri = _lower_tri(q)
     # mask *before* exp: the masked (upper) entries hold positive log-decays
     # that could overflow fp32 for long chunks / large dt·|A|
     L = jnp.exp(jnp.where(tri, li, -jnp.inf))
-    return da, cs, L
+    return cs, da.sum(), L
 
 
 # ---------------------------------------------------------------------------
@@ -90,13 +110,13 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *refs,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (q, p)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (q,)
-    a = a_ref[0]                                  # scalar A (negative)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)     # (q,)
+    a = a_ref[pl.program_id(1)]                   # scalar A (negative), SMEM
     bmat = b_ref[0, 0].astype(jnp.float32)       # (q, n)
     cmat = c_ref[0, 0].astype(jnp.float32)       # (q, n)
 
     xd = x * dt[:, None]
-    _, cs, L = _chunk_decay(dt, a)
+    cs, cs_end, L = _chunk_decay(dt, a)
 
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * L
@@ -111,8 +131,8 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *refs,
         preferred_element_type=jnp.float32)
 
     # state recurrence: state' = exp(cs[-1])·state + Σ_q exp(cs[-1]-cs)·xdᵀB
-    decay_states = jnp.exp(cs[-1] - cs)           # (q,)
-    state_new = (state * jnp.exp(cs[-1])
+    decay_states = jnp.exp(cs_end - cs)           # (q,)
+    state_new = (state * jnp.exp(cs_end)
                  + jax.lax.dot_general(xd * decay_states[:, None], bmat,
                                        (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32))
@@ -155,8 +175,8 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
+            _SMEM,
             pl.BlockSpec((1, 1, chunk, n),
                          lambda bi, hi, ci, g_=hpg: (bi, hi // g_, ci, 0)),
             pl.BlockSpec((1, 1, chunk, n),
@@ -166,7 +186,8 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool):
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), Bm, Cm)
+        name="ssd_fwd",
+    )(x, dt[:, :, None, :], A.astype(jnp.float32), Bm, Cm)
     if save_enters:
         return outs[0], outs[1], outs[2]
     return outs[0], None, outs[1]
@@ -187,8 +208,8 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
         dstate_ref[...] = dsf_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (q, p)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (q,)
-    a = a_ref[0]
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)     # (q,)
+    a = a_ref[pl.program_id(1)]
     bmat = b_ref[0, 0].astype(jnp.float32)       # (q, n)
     cmat = c_ref[0, 0].astype(jnp.float32)       # (q, n)
     sin = enter_ref[0, 0, 0].astype(jnp.float32)  # (p, n) state entering chunk
@@ -196,9 +217,9 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
     ds_out = dstate_ref[...]                      # (p, n) cotangent of S_out
 
     xd = x * dt[:, None]
-    _, cs, L = _chunk_decay(dt, a)
+    cs, cs_end, L = _chunk_decay(dt, a)
     exp_cs = jnp.exp(cs)
-    decay_states = jnp.exp(cs[-1] - cs)           # (q,)
+    decay_states = jnp.exp(cs_end - cs)           # (q,)
 
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (q, q)
@@ -232,27 +253,29 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
     G = dscores * scores                           # dL ∘ L, zero above diagonal
     dcs = G.sum(axis=1) - G.sum(axis=0)
     dcs = dcs + (dy * y_off).sum(axis=-1)          # exp(cs) factor in y_off
-    t = decay_states * (xd_ds * bmat).sum(axis=-1)  # exp(cs[-1]-cs) factor
-    dcs = dcs - t
+    t_qn = decay_states[:, None] * xd_ds * bmat    # exp(cs[-1]-cs) factor
+    dcs = dcs - t_qn.sum(axis=-1)
     # the two cs[-1] contributions (Σt from decay_states, exp(cs[-1])·sin term)
     # land on every entry of the reverse cumsum below, so fold them into the
-    # total instead of scattering into index q-1
-    last = t.sum() + jnp.exp(cs[-1]) * (ds_out * sin).sum()
+    # total instead of scattering into index q-1. Both totals reduce 2-D
+    # tiles: the kernel lowering cannot broadcast the scalar sum of a
+    # row-reduced vector back over a vector.
+    last = t_qn.sum() + jnp.exp(cs_end) * (ds_out * sin).sum()
 
     # cs = cumsum(da)  =>  dda_i = Σ_{j>=i} dcs_j  (+ last, which sits at j=q-1)
-    dda = (dcs.sum() + last) - jnp.cumsum(dcs) + dcs
+    dda = _cumsum(dcs, reverse=True) + last
 
     ddt = dda * a + (dxd * x).sum(axis=-1)
     dx = dxd * dt[:, None]
 
     # propagate the state cotangent to the previous chunk
-    dstate_ref[...] = (jnp.exp(cs[-1]) * ds_out
+    dstate_ref[...] = (jnp.exp(cs_end) * ds_out
                        + jax.lax.dot_general(dy_e, cmat, (((0,), (0,)), ((), ())),
                                              preferred_element_type=jnp.float32))
 
     dx_ref[0, 0] = dx.astype(dx_ref.dtype)
-    ddt_ref[0, 0] = ddt.astype(ddt_ref.dtype)
-    dda_ref[0, 0] = dda.astype(dda_ref.dtype)
+    ddt_ref[0, 0, 0] = ddt.astype(ddt_ref.dtype)
+    dda_ref[0, 0, 0] = dda.astype(dda_ref.dtype)
     db_ref[0, 0] = db.astype(db_ref.dtype)
     dc_ref[0, 0] = dc.astype(dc_ref.dtype)
 
@@ -266,6 +289,8 @@ def _ssd_backward(chunk, interpret, res, g):
     nc = l // chunk
     grid = (b, h, nc)
     rev = nc - 1   # index maps sweep chunks last -> first
+    row_spec = pl.BlockSpec((1, 1, 1, chunk),
+                            lambda bi, hi, ci, r=rev: (bi, hi, 0, r - ci))
 
     dx, ddt, dda, db, dc = pl.pallas_call(
         _bwd_kernel,
@@ -273,9 +298,8 @@ def _ssd_backward(chunk, interpret, res, g):
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p),
                          lambda bi, hi, ci, r=rev: (bi, hi, r - ci, 0)),
-            pl.BlockSpec((1, 1, chunk),
-                         lambda bi, hi, ci, r=rev: (bi, hi, r - ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            row_spec,
+            _SMEM,
             pl.BlockSpec((1, 1, chunk, n),
                          lambda bi, hi, ci, g_=hpg, r=rev: (bi, hi // g_, r - ci, 0)),
             pl.BlockSpec((1, 1, chunk, n),
@@ -289,10 +313,8 @@ def _ssd_backward(chunk, interpret, res, g):
         out_specs=[
             pl.BlockSpec((1, 1, chunk, p),
                          lambda bi, hi, ci, r=rev: (bi, hi, r - ci, 0)),
-            pl.BlockSpec((1, 1, chunk),
-                         lambda bi, hi, ci, r=rev: (bi, hi, r - ci)),
-            pl.BlockSpec((1, 1, chunk),
-                         lambda bi, hi, ci, r=rev: (bi, hi, r - ci)),
+            row_spec,
+            row_spec,
             pl.BlockSpec((1, 1, chunk, n),
                          lambda bi, hi, ci, r=rev: (bi, hi, r - ci, 0)),
             pl.BlockSpec((1, 1, chunk, n),
@@ -300,15 +322,17 @@ def _ssd_backward(chunk, interpret, res, g):
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, l, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, l), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, l), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, l), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, l), jnp.float32),
             jax.ShapeDtypeStruct((b, h, l, n), jnp.float32),
             jax.ShapeDtypeStruct((b, h, l, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), Bm, Cm, enters,
+        name="ssd_bwd",
+    )(x, dt[:, :, None, :], A.astype(jnp.float32), Bm, Cm, enters,
       dy.astype(jnp.float32), dsf.astype(jnp.float32))
+    ddt, dda = ddt[:, :, 0], dda[:, :, 0]
 
     # per-head B/C gradients -> group-sum onto the shared projection (GQA trick)
     dB = db.reshape(b, grp, hpg, l, n).sum(axis=2).astype(Bm.dtype)

@@ -13,6 +13,7 @@ import jax               # noqa: E402
 
 from repro.core import ARCH_IDS, INPUT_SHAPES, ParallelPlan, SHAPES_BY_NAME  # noqa: E402
 from repro.core.config import Family  # noqa: E402
+from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.stepbuilder import build_step, jit_step, resolve_config, skip_reason  # noqa: E402
 from repro.perf import Roofline, model_flops_for  # noqa: E402
@@ -166,6 +167,7 @@ def main() -> None:
     ap.add_argument("--moe-dispatch", default=None,
                     choices=["einsum", "scatter", None])
     args = ap.parse_args()
+    use_compile_cache()
 
     assert len(jax.devices()) == 512, "dry-run requires 512 placeholder devices"
 

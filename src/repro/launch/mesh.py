@@ -15,9 +15,45 @@ once-per-step grad reduction but lighter than TP's per-GEMM rings.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def cpu_child_env(n_devices: int = 1) -> Dict[str, str]:
+    """Environment entries for a child process that runs JAX on
+    ``n_devices`` virtual CPU devices.
+
+    The child is pinned to the CPU whatever its parent holds: on a machine
+    with a chip the parent may own it, and a child that reached for it would
+    fail or hang. XLA:CPU keys a collective's rendezvous on its channel id,
+    and every shard_map collective carries channel id 1; its default
+    (concurrency-optimized) scheduler may start two data-independent
+    collective-permutes of one program at once, which then share one
+    rendezvous and abort ("id < num_threads"). Every device enters every
+    collective, so the program is sound; the sequential scheduler runs one
+    collective at a time.
+    """
+    return {
+        "JAX_PLATFORMS": "cpu",
+        "XLA_FLAGS": (f"--xla_force_host_platform_device_count={n_devices} "
+                      "--xla_cpu_enable_concurrency_optimized_scheduler=false"),
+    }
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """The one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` and the GSPMD constrainers refuse to run.
+    This code places arrays with ``NamedSharding`` and ``shard_map``, which
+    is what Auto axes are for. ``devices`` lets a compile rehearsal hand in
+    the devices of a described (not attached) topology.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, cp: int = 1):
@@ -29,10 +65,10 @@ def make_production_mesh(*, multi_pod: bool = False, cp: int = 1):
         shape = (2, 16 // cp, cp, 16) if multi_pod else (16 // cp, cp, 16)
         axes = (("pod", "data", "cp", "model") if multi_pod
                 else ("data", "cp", "model"))
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(shape: Tuple[int, ...] = None, axes: Tuple[str, ...] = None):
@@ -41,7 +77,7 @@ def make_local_mesh(shape: Tuple[int, ...] = None, axes: Tuple[str, ...] = None)
     if shape is None:
         shape = (1, n) if n > 1 else (1, 1)
         axes = ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def shrink_mesh(mesh, axis: str, lost: int = 1):
@@ -61,7 +97,8 @@ def shrink_mesh(mesh, axis: str, lost: int = 1):
     dim = mesh.axis_names.index(axis)
     keep = [slice(None)] * mesh.devices.ndim
     keep[dim] = slice(0, size - lost)
-    return Mesh(mesh.devices[tuple(keep)], mesh.axis_names)
+    return Mesh(mesh.devices[tuple(keep)], mesh.axis_names,
+                axis_types=mesh.axis_types)
 
 
 def batch_axes_for(mesh, global_batch: int, pp: int = 1,

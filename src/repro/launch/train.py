@@ -1,10 +1,12 @@
 """End-to-end training driver.
 
-Runs real steps on the available devices (CPU in this container; the same code
-path drives a TPU slice — the mesh is the only difference):
+Runs real steps on the available devices (one TPU chip, a TPU slice through
+the local mesh, or the CPU):
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --smoke \\
         --steps 50 --batch 8 --seq 128
+    PYTHONPATH=src python -m repro.launch.train --arch mamba2-370m --full \\
+        --steps 100 --batch 8 --seq 2048 --remat full   # one v5e chip
 
 Integrates the full substrate: synthetic data pipeline, sharded AdamW + ZeRO-1,
 remat, checkpointing (async persist, optional double-buffered snapshots), and
@@ -22,6 +24,10 @@ a just-in-time snapshot within ``--preempt-grace`` seconds, writes a
 bit-identically. ``--flight-path`` arms the crash flight recorder: a
 bounded ring of per-step events dumped to JSON on preemption, crash, or
 recovery exhaustion for post-mortem attribution.
+
+The step is compiled ahead of the loop, so compile time is reported apart
+from step time; :func:`main` returns a :class:`TrainResult` for callers that
+drive the trainer in-process (``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -29,6 +35,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,16 +44,25 @@ from repro.core import ARCH_IDS, InputShape, ParallelPlan, RecoveryPolicy
 from repro.core.config import RECOVERY_ACTIONS, Family
 from repro.checkpoint import CheckpointManager, MemoryCheckpointTier
 from repro.data import Prefetcher, SyntheticDataset
-from repro.ft import (FlightRecorder, Monitor, StragglerTimer,
+from repro.ft import (FlightRecorder, Monitor, RunReport, StragglerTimer,
                       run_with_recovery)
 from repro.ft.preempt import PreemptionGuard
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import batch_axes_for, make_local_mesh
 from repro.launch.stepbuilder import resolve_config
 from repro.models import build_model
 from repro.train import Hyper, TrainState, init_train_state, make_train_step
 
 
-def main() -> None:
+@dataclasses.dataclass
+class TrainResult:
+    report: RunReport
+    compile_seconds: float
+    compiled: Any          # the train step as compiled (jax.stages.Compiled)
+    flight: FlightRecorder  # per-step events: losses, checkpoint seconds
+
+
+def main(argv: Optional[Sequence[str]] = None) -> TrainResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true", default=True,
@@ -139,7 +155,8 @@ def main() -> None:
                     help="where the flight recorder dumps its JSON on "
                          "preemption/crash/exhaustion (default: "
                          "<ckpt-dir>/flight.json)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = resolve_config(args.arch, "train_4k", smoke=args.smoke)
     shape = InputShape("cli", args.seq, args.batch, "train")
@@ -202,12 +219,20 @@ def main() -> None:
     straggler = StragglerTimer(cfg=cfg, plan=plan, policy=policy,
                                flight=flight)
 
-    t_start = time.time()
     prefetch = Prefetcher(ds) if args.prefetch else None
     source = prefetch.batch if prefetch is not None else ds.batch
 
     def get_batch(step: int):
         return {k: jnp.asarray(v) for k, v in source(step).items()}
+
+    # compile ahead of the loop: the jit call below reuses this executable,
+    # so the first step's time is a step time, not a compile time
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(state, get_batch(0)).compile()
+    compile_seconds = time.perf_counter() - t0
+    print(f"[train] step compiled in {compile_seconds:.1f}s")
+
+    t_start = time.time()
 
     def injector(step, st):
         if step == args.simulate_hang_at:
@@ -240,7 +265,7 @@ def main() -> None:
               f"(signal {guard.signum}): just-in-time snapshot taken, "
               f"PREEMPTED marker written, flight log at "
               f"{report.flight_path}; rerun with --resume to continue")
-        return
+        return TrainResult(report, compile_seconds, compiled, flight)
     tokens = args.steps * args.batch * args.seq
     print(f"[train] {args.steps} steps in {dt:.1f}s "
           f"({tokens/dt:.0f} tok/s), loss {report.losses[0]:.4f} -> "
@@ -253,6 +278,7 @@ def main() -> None:
           f"persist {ckpt.persist_seconds*1e3:.1f}ms "
           f"({'double-buffered' if args.async_snapshot else 'blocking'} "
           f"snapshot, async persist)")
+    return TrainResult(report, compile_seconds, compiled, flight)
 
 
 if __name__ == "__main__":

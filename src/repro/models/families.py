@@ -15,6 +15,7 @@ scanned metadata array rather than unrolled python branches.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -23,7 +24,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.config import Family, ModelConfig, ParallelPlan
+from repro.core.config import KERNEL_KNOBS, Family, ModelConfig, ParallelPlan
 from . import moe as moe_lib
 from . import ssm as ssm_lib
 from .layers import (
@@ -716,10 +717,33 @@ def build_enc_dec(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
 
 # ---------------------------------------------------------------------------
 
+def gspmd_kernel_plan(plan: ParallelPlan, mesh) -> ParallelPlan:
+    """The plan the GSPMD model path runs its kernels under.
+
+    XLA's SPMD partitioner cannot split a Mosaic (Pallas TPU) kernel, so on a
+    mesh of more than one device an ``"auto"`` kernel choice resolves to
+    XLA here. An explicit ``"pallas"`` cannot be honoured on a TPU backend
+    and raises; off-TPU the kernel runs interpreted, as plain HLO that
+    partitions. The shard_map paths (the executor's overlap TP, cp and ep,
+    and the pipeline) build their layers from the plan as given and call the
+    kernels per shard."""
+    if mesh is None or mesh.size == 1:
+        return plan
+    explicit = [k for k in KERNEL_KNOBS if getattr(plan, k) == "pallas"]
+    if explicit and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"{', '.join(explicit)}='pallas' cannot run on the GSPMD path of "
+            f"a {mesh.size}-device mesh: GSPMD cannot partition a Mosaic "
+            "kernel; use 'auto' or a shard_map path (tp_impl='overlap')")
+    return dataclasses.replace(plan, **{k: "xla" for k in KERNEL_KNOBS
+                                        if getattr(plan, k) == "auto"})
+
+
 def build_model(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
                 mesh=None, batch_axes=("data",)) -> Model:
     if plan is not None:
         plan.validate(cfg)
+        plan = gspmd_kernel_plan(plan, mesh)
     if cfg.family == Family.SSM:
         return build_ssm(cfg, plan, mesh, batch_axes)
     if cfg.family == Family.HYBRID:

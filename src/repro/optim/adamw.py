@@ -20,7 +20,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 class AdamWState(NamedTuple):
@@ -40,14 +40,14 @@ def adamw_init(params: Any, *, mesh: Mesh = None,
         zeros = lambda p, s: jnp.zeros(p.shape, jnp.float32,
                                        device=NamedSharding(mesh, s))
         moments = lambda: jax.tree.map(zeros, params, specs)
+        # replicated over the mesh, as the step returns it: a counter on one
+        # device would give the second step another input layout to compile
+        step = jnp.zeros((), jnp.int32, device=NamedSharding(mesh, P()))
     else:
         zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
         moments = lambda: jax.tree.map(zeros, params)
-    return AdamWState(
-        step=jnp.zeros((), jnp.int32),
-        mu=moments(),
-        nu=moments(),
-    )
+        step = jnp.zeros((), jnp.int32)
+    return AdamWState(step=step, mu=moments(), nu=moments())
 
 
 def adamw_update(

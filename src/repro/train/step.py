@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import sharding as shardlib
 from repro.core.config import ModelConfig, ParallelPlan
@@ -67,12 +67,27 @@ def init_train_state(model: Model, rng, mesh: Optional[Mesh] = None,
     params = model.init(rng)
     if mesh is None or plan is None:
         return TrainState(params, adamw_init(params))
-    pspecs = shardlib.param_specs(params, model.cfg, plan, mesh)
+    pspecs = jax.tree.map(_canonical,
+                          shardlib.param_specs(params, model.cfg, plan, mesh),
+                          is_leaf=lambda x: isinstance(x, P))
     params = jax.tree.map(
         lambda p, s: jax.device_put(p, jax.sharding.NamedSharding(mesh, s)),
         params, pspecs)
-    ospecs = shardlib.opt_state_specs(pspecs, params, plan, mesh)
+    ospecs = jax.tree.map(_canonical,
+                          shardlib.opt_state_specs(pspecs, params, plan, mesh),
+                          is_leaf=lambda x: isinstance(x, P))
     return TrainState(params, adamw_init(params, mesh=mesh, specs=ospecs))
+
+
+def _canonical(spec: P) -> P:
+    """``spec`` without trailing ``None`` entries — the form a jitted step
+    hands its outputs back in. jit keys its cache on the spec as written, so
+    a state placed as ``P(None, None)`` would make the second step compile
+    again for the ``P()`` the first step returned."""
+    parts = list(spec)
+    while parts and parts[-1] is None:
+        parts.pop()
+    return P(*parts)
 
 
 def make_loss_fn(model: Model, hyper: Hyper) -> Callable:

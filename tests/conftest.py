@@ -6,6 +6,8 @@ from pathlib import Path
 
 import pytest
 
+from repro.launch.mesh import cpu_child_env
+
 REPO = Path(__file__).resolve().parent.parent
 SRC = REPO / "src"
 
@@ -14,10 +16,10 @@ def run_multidevice(script: str, n_devices: int = 8, timeout: int = 600):
     """Run a python snippet in a subprocess with N forced host devices.
 
     Tests and benches in-process must see 1 device (per the dry-run contract),
-    so anything needing a mesh runs out-of-process.
+    so anything needing a mesh runs out-of-process, pinned to the CPU
+    (``repro.launch.mesh.cpu_child_env``).
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env = dict(os.environ, **cpu_child_env(n_devices))
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(script)],

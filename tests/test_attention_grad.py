@@ -83,9 +83,12 @@ def test_dispatch_rules():
     # explicit choices always honored (static masks)
     assert select_impl("xla", head_dim=128, window=0, q_offset=0) == "xla"
     assert select_impl("pallas", head_dim=128, window=0, q_offset=0) == "pallas"
-    # traced mask params (gemma2 alternation) force XLA
+    # traced mask params (gemma2 alternation): auto takes XLA, an explicit
+    # pallas request raises instead of quietly running XLA
     traced = jnp.int32(4)
-    assert select_impl("pallas", head_dim=128, window=traced, q_offset=0) == "xla"
+    assert select_impl("auto", head_dim=128, window=traced, q_offset=0) == "xla"
+    with pytest.raises(ValueError, match="static window"):
+        select_impl("pallas", head_dim=128, window=traced, q_offset=0)
     # auto never picks the interpreter off-TPU
     expected = "pallas" if jax.default_backend() == "tpu" else "xla"
     assert select_impl("auto", head_dim=128, window=0, q_offset=0) == expected

@@ -405,11 +405,12 @@ from repro.ft import Monitor, run_with_recovery
 from repro.ft.inject import FaultSpec, armed, make_injector, trace_with_faults
 from repro.models import build_model
 from repro.train import Hyper, init_train_state, make_train_step
+from repro.launch.mesh import make_mesh
 
 cfg = {cfg}
 plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2,
                     zero_stage=1, integrity="audit"{plan_extra})
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 model = build_model(cfg, plan, mesh, ("data",))
 ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))
 get_batch = lambda s: {{k: jnp.asarray(v) for k, v in ds.batch(s).items()}}
@@ -526,6 +527,7 @@ from repro.core import (Family, InputShape, ModelConfig, MoEConfig, SSMConfig,
 from repro.data import SyntheticDataset
 from repro.ft import Monitor, StragglerDetector, StragglerTimer, \\
     run_with_recovery
+from repro.launch.mesh import make_mesh
 from repro.ft.inject import FaultSpec, armed
 from repro.models import build_model
 from repro.train import Hyper, init_train_state, make_train_step
@@ -533,7 +535,7 @@ from repro.train import Hyper, init_train_state, make_train_step
 cfg = {cfg}
 plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2,
                     zero_stage=1, integrity="audit"{plan_extra})
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 model = build_model(cfg, plan, mesh, ("data",))
 ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))
 get_batch = lambda s: {{k: jnp.asarray(v) for k, v in ds.batch(s).items()}}
@@ -613,8 +615,9 @@ def test_sdc_detected_multidevice(multidevice):
 import jax, jax.numpy as jnp
 from repro.ft.inject import FaultSpec, trace_with_faults
 from repro.ft.integrity import replica_divergence
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 tree = {"w": jnp.arange(64, dtype=jnp.float32)}
 
 def audit(t):

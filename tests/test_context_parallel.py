@@ -246,6 +246,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = {cfg}
 shape = InputShape("t", 16, 8, "train")
@@ -261,7 +262,7 @@ ref_loss, ref_g = jax.jit(
     jax.value_and_grad(lambda p, b: lf(p, b)[0]))(params, batch)
 
 for mesh_shape in [(1, 2), (2, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "cp"))
+    mesh = make_mesh(mesh_shape, ("data", "cp"))
     for impl in ("gather", "ring"):
         plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2,
                             cp_impl=impl)
@@ -318,6 +319,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
@@ -333,7 +335,7 @@ ref_loss, ref_g = jax.jit(
     jax.value_and_grad(lambda p, b: lf(p, b)[0]))(params, batch)
 
 for mesh_shape in [(1, 2, 2), (2, 2, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "cp", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "cp", "model"))
     plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2, tp=2,
                         tp_impl="overlap", cp_impl="ring")
     clf = make_executor_loss_fn(cfg, plan, mesh, ("data",), z_loss=Z)
@@ -364,6 +366,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -378,7 +381,7 @@ ref_loss, _ = make_loss_fn(model, Hyper(z_loss=Z))(params, batch)
 ref_g = jax.grad(lambda p, b: make_loss_fn(model, Hyper(z_loss=Z))(p, b)[0])(
     params, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "cp"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "cp"))
 for sched in ("gpipe", "1f1b"):
     plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, cp=2,
                         microbatches=4, pp_schedule=sched, cp_impl="ring")
@@ -394,7 +397,7 @@ for sched in ("gpipe", "1f1b"):
     print(sched, "CP x PP == single-device OK")
 
 # CP x TP x PP: all three explicit axes in one 1F1B tick
-mesh = jax.make_mesh((2, 2, 2), ("pod", "cp", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "cp", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, cp=2, tp=2,
                     microbatches=4, tp_impl="overlap", cp_impl="ring")
 lf = pipelined_loss_fn(cfg, plan, mesh, (), z_loss=Z)
@@ -421,13 +424,14 @@ from repro.models import build_model
 from repro.optim import adamw_init
 from repro.train import Hyper, TrainState, make_loss_fn, make_train_step
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
 shape = InputShape("t", 16, 8, "train")
 ds = SyntheticDataset(cfg, shape)
 batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 
 g0 = None
 for remat in ("none", "selective", "full"):
@@ -474,8 +478,9 @@ import jax, jax.numpy as jnp, numpy as np, json, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.core import Family, ModelConfig, ParallelPlan
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 plan = ParallelPlan(cp=2, cp_impl="ring")
 cfg = ModelConfig("t", Family.DENSE, 2, 64, 4, 2, 128, 128)
 rng = np.random.default_rng(0)

@@ -65,8 +65,9 @@ def test_distributed_decode_attention(multidevice):
     multidevice("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.serve.attention import decode_attention
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 b, t, hq, hkv, hd = 4, 32, 8, 2, 16
 q = jnp.asarray(rng.standard_normal((b, 1, hq, hd)), jnp.float32)

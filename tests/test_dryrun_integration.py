@@ -12,8 +12,9 @@ from repro.core import ParallelPlan, SHAPES_BY_NAME
 from repro.core.config import Family, InputShape
 from repro.launch.stepbuilder import build_step, resolve_config
 from repro.perf.hlo_cost import analyze_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 arch = "{arch}"
 cfg = resolve_config(arch, "train_4k", smoke=True)
 # MoE archs fold the expert ring onto the 4-wide model axis (ep is a

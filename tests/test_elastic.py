@@ -342,10 +342,11 @@ def test_restore_resharded_cross_mesh(multidevice):
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 devs = jax.devices()
-m1 = jax.make_mesh((4,), ("data",))
-m2 = jax.make_mesh((2, 2), ("data", "model"))
+m1 = make_mesh((4,), ("data",))
+m2 = make_mesh((2, 2), ("data", "model"))
 x = jax.device_put(jnp.arange(32 * 32, dtype=jnp.float32).reshape(32, 32),
                    NamedSharding(m1, P("data", None)))
 mgr = CheckpointManager(tempfile.mkdtemp(), async_persist=False)
@@ -381,6 +382,7 @@ from repro.ft import Monitor, RemeshSpec, run_with_recovery
 from repro.launch.mesh import shrink_mesh
 from repro.models import build_model
 from repro.train import Hyper, init_train_state, make_train_step
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -390,7 +392,7 @@ ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))
 get_batch = lambda s: {k: jnp.asarray(v) for k, v in ds.batch(s).items()}
 N, FAULT, EVERY = 20, 13, 5
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 model = build_model(cfg, plan, mesh, ("data",))
 state0 = init_train_state(model, jax.random.PRNGKey(0), mesh=mesh, plan=plan)
 step_big = jax.jit(make_train_step(model, plan, hyper, mesh=mesh))

@@ -193,6 +193,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.executor import make_executor_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = {cfg}
 shape = InputShape("t", 16, 8, "train")
@@ -222,7 +223,7 @@ def check(tag, plan, mesh, baxes, atol):
 
 # ep-only: 1x2 and 2x2 (data, model) meshes — experts ride the model axis
 for mesh_shape in [(1, 2), (2, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     for impl in ("blocking", "overlap"):
         plan = ParallelPlan(remat="none", compute_dtype="float32", ep=2,
                             ep_impl=impl{extra_knobs})
@@ -230,7 +231,7 @@ for mesh_shape in [(1, 2), (2, 2)]:
 
 # folded: ep == cp x tp == 4 on a (data, cp, model) mesh — attention and
 # MoE use different mappings of the same four devices
-mesh = jax.make_mesh((1, 2, 2), ("data", "cp", "model"))
+mesh = make_mesh((1, 2, 2), ("data", "cp", "model"))
 for impl in ("blocking", "overlap"):
     plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2, tp=2,
                         tp_impl="overlap", cp_impl="ring", ep=4,
@@ -287,6 +288,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tmoe", Family.MOE, n_layers=4, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=0, vocab=128,
@@ -324,7 +326,7 @@ def check(tag, plan, mesh, baxes, atol):
     print(tag, "== per-microbatch oracle, loss", float(pl))
 
 # EP x CP x PP: the expert ring folds onto cp alone, both schedules
-mesh = jax.make_mesh((2, 1, 2), ("pod", "data", "cp"))
+mesh = make_mesh((2, 1, 2), ("pod", "data", "cp"))
 for sched in ("gpipe", "1f1b"):
     plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, cp=2,
                         ep=2, ep_impl="overlap", microbatches=M,
@@ -332,14 +334,14 @@ for sched in ("gpipe", "1f1b"):
     check(("ep x cp x pp", sched), plan, mesh, ("data",), 1e-6)
 
 # EP x TP x CP x PP: all four explicit axes in one 1F1B tick
-mesh = jax.make_mesh((2, 2, 2), ("pod", "cp", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "cp", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, cp=2, tp=2,
                     ep=4, ep_impl="overlap", microbatches=M,
                     tp_impl="overlap", cp_impl="ring")
 check("ep x tp x cp x pp (1f1b)", plan, mesh, (), 3e-6)
 
 # ep-only has no axis to fold onto under pp — rejected, not mislaid
-mesh = jax.make_mesh((2, 1), ("pod", "data"))
+mesh = make_mesh((2, 1), ("pod", "data"))
 try:
     pipelined_loss_fn(cfg, ParallelPlan(pp=2, ep=2, microbatches=M),
                       mesh, ("data",))
@@ -365,6 +367,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import Family, ModelConfig, MoEConfig, ParallelPlan
 from repro.core.sharding import ep_spec_for_param
 from repro.models.moe import init_moe
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tmoe", Family.MOE, 2, 64, 4, 2, 0, 128,
                   moe=MoEConfig(num_experts=4, top_k=2, d_expert=64,
@@ -372,7 +375,7 @@ cfg = ModelConfig("tmoe", Family.MOE, 2, 64, 4, 2, 0, 128,
 params = init_moe(jax.random.PRNGKey(0), cfg)
 
 # save under the ep-only layout: experts over a 2-wide model axis
-mesh_a = jax.make_mesh((1, 2), ("data", "model"))
+mesh_a = make_mesh((1, 2), ("data", "model"))
 plan_a = ParallelPlan(ep=2, ep_impl="overlap")
 
 def place(params, plan, mesh):
@@ -407,7 +410,7 @@ with tempfile.TemporaryDirectory() as d:
 
     # elastic reshard: restore onto the folded ep=4 layout (cp x model)
     plan_b = ParallelPlan(ep=4, cp=2, tp=2, tp_impl="overlap")
-    mesh_b = jax.make_mesh((1, 2, 2), ("data", "cp", "model"))
+    mesh_b = make_mesh((1, 2, 2), ("data", "cp", "model"))
     def shardings(path, leaf):
         names = tuple(str(getattr(p, "key", p)) for p in path)
         spec = ep_spec_for_param(names, tuple(leaf.shape), plan_b)

@@ -86,6 +86,27 @@ def test_memory_tier_roundtrip_and_ring_eviction():
         mem.restore(tree)
 
 
+def test_memory_tier_evicts_before_building(monkeypatch):
+    """A save never holds keep + 1 snapshots in RAM: the oldest entry goes
+    before the new one's host copies are made."""
+    import repro.checkpoint.memory as M
+
+    tree = {"w": jnp.ones((4, 6), jnp.float32)}
+    mem = MemoryCheckpointTier(keep=2, groups=2)
+    held = []
+    orig = M._leaf_shards
+
+    def spy(x, copy=False):
+        held.append(len(mem._ring))
+        return orig(x, copy=copy)
+
+    monkeypatch.setattr(M, "_leaf_shards", spy)
+    for s in range(4):
+        mem.save(s, tree)
+    assert held == [0, 1, 1, 1]
+    assert mem.steps() == [2, 3]
+
+
 def test_memory_tier_peer_rebuild_bit_matches_disk(tmp_path):
     """Acceptance: after a simulated lost host-group, the peer-rebuilt RAM
     restore bit-matches the disk restore of the same step — on a real train

@@ -65,7 +65,8 @@ def test_collectives_parsed_with_group_size(multidevice):
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.perf.hlo_cost import analyze_hlo
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def f(w, x):
     return (x @ w).sum()
@@ -111,16 +112,18 @@ def test_all_to_all_priced_from_lowered(multidevice):
     multidevice("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from repro.core.compat import shard_map
 from repro.perf.hlo_cost import analyze_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 
 def body(x):
     return jax.lax.all_to_all(x, "model", split_axis=0, concat_axis=0,
                               tiled=False)
 
-f = shard_map(body, mesh, in_specs=P(None, "model"), out_specs=P(None, "model"))
+f = shard_map(body, mesh=mesh, in_specs=P(None, "model"),
+              out_specs=P(None, "model"))
 x = jax.ShapeDtypeStruct((8, 64, 32), jnp.float32)
 a = analyze_hlo(jax.jit(f).lower(x).compile().as_text(), 8)
 assert a.collective_counts["all-to-all"] == 1, a.collective_counts

@@ -18,6 +18,7 @@ from repro.kernels import (
 )
 from repro.kernels.ref import expert_gemm_ref
 from repro.models import build_model
+from repro.models.families import gspmd_kernel_plan
 from repro.models.ssm import ssd_scan
 from repro.train import Hyper, init_train_state, make_train_step
 
@@ -140,8 +141,32 @@ def test_per_op_dispatch_rules():
         assert sel("auto") == expected
         with pytest.raises(ValueError):
             sel("cuda")
-    # the fused SSD kernel starts from a zero state
-    assert select_ssd_impl("pallas", has_initial_state=True) == "xla"
+    # the fused SSD kernel starts from a zero state: auto takes the XLA scan,
+    # an explicit pallas request raises
+    assert select_ssd_impl("auto", has_initial_state=True) == "xla"
+    with pytest.raises(ValueError, match="zero state"):
+        select_ssd_impl("pallas", has_initial_state=True)
+
+
+def test_gspmd_kernel_plan_rules(monkeypatch):
+    """On a mesh the GSPMD model path resolves "auto" kernels to XLA (GSPMD
+    cannot partition a Mosaic kernel) and refuses an explicit "pallas" on a
+    TPU backend; one device, or no mesh, keeps the plan as given."""
+    class Mesh:                              # the one attribute read
+        def __init__(self, size):
+            self.size = size
+
+    plan = ParallelPlan(attn_impl="auto", moe_gemm_impl="xla",
+                        ssm_impl="pallas")
+    assert gspmd_kernel_plan(plan, None) is plan
+    assert gspmd_kernel_plan(plan, Mesh(1)) is plan
+    got = gspmd_kernel_plan(plan, Mesh(4))   # off-TPU pallas interprets
+    assert (got.attn_impl, got.moe_gemm_impl, got.ssm_impl) == \
+        ("xla", "xla", "pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="ssm_impl='pallas'.*GSPMD"):
+        gspmd_kernel_plan(plan, Mesh(4))
+    assert gspmd_kernel_plan(plan, Mesh(1)) is plan
 
 
 def test_plan_validates_impl_knobs():

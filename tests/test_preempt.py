@@ -215,11 +215,12 @@ from repro.ft import FlightRecorder, Monitor, run_with_recovery
 from repro.ft.preempt import PreemptionGuard, read_marker
 from repro.models import build_model
 from repro.train import Hyper, init_train_state, make_train_step
+from repro.launch.mesh import make_mesh
 
 cfg = {cfg}
 plan = ParallelPlan(remat="none", compute_dtype="float32", cp=2,
                     zero_stage=1{plan_extra})
-mesh = jax.make_mesh((2, 2), ("data", "cp"))
+mesh = make_mesh((2, 2), ("data", "cp"))
 model = build_model(cfg, plan, mesh, ("data",))
 ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))
 get_batch = lambda s: {{k: jnp.asarray(v) for k, v in ds.batch(s).items()}}

@@ -403,6 +403,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
@@ -415,7 +416,7 @@ ref_loss, _ = make_loss_fn(model, Hyper(z_loss=0.0))(params, batch)
 ref_g = jax.grad(lambda p, b: make_loss_fn(model, Hyper(z_loss=0.0))(p, b)[0]
                  )(params, batch)
 
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 base = ParallelPlan(remat="none", compute_dtype="float32", pp=2,
                     microbatches=4)
 for layout in [(2, 2), (3, 1), (1, 3)]:
@@ -450,10 +451,11 @@ from repro.ft import (Monitor, RemeshSpec, StragglerDetector, StragglerTimer,
 from repro.ft.inject import FaultSpec, armed
 from repro.models import build_model
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
-mesh = jax.make_mesh((2, 2), ("pod", "data"))
+mesh = make_mesh((2, 2), ("pod", "data"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2,
                     microbatches=4)
 ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))

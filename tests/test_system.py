@@ -92,6 +92,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, TrainState, init_train_state, make_train_step
 from repro.optim import adamw_init
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -107,7 +108,7 @@ s0 = init_train_state(m0, jax.random.PRNGKey(0))
 ref_state, ref_metrics = jax.jit(make_train_step(m0, plan0, hyper))(s0, batch)
 
 # sharded: (data=2, model=4) mesh with TP+ZeRO1
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", tp=4, zero_stage=1)
 m1 = build_model(cfg, plan, mesh, ("data",))
 s1 = init_train_state(m1, jax.random.PRNGKey(0))
@@ -135,6 +136,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -148,7 +150,7 @@ model = build_model(cfg, plan0)
 params = model.init(jax.random.PRNGKey(0))
 ref_loss, _ = make_loss_fn(model, hyper)(params, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 # pin the gpipe schedule: this test covers reverse-AD through the forward
 # scan; tests/test_train_memory.py covers 1f1b (and both against gpipe)
 plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2,

@@ -106,13 +106,16 @@ from repro.core.compat import shard_map
 from repro.train.tensor_parallel import (RingCtx, all_gather_matmul,
                                          matmul_reduce_scatter,
                                          ring_all_gather, ring_reduce_scatter)
+from repro.launch.mesh import make_mesh
 
 rng = np.random.default_rng(0)
 B, S, D, F, T = 2, 8, 6, 10, 2
-x = jnp.asarray(rng.standard_normal((B, S, D)), jnp.float32)
-w1 = jnp.asarray(rng.standard_normal((D, F)), jnp.float32)
-w2 = jnp.asarray(rng.standard_normal((F, D)), jnp.float32)
-mesh = jax.make_mesh((T,), ("model",))
+# small integers: every product and partial sum is exact in fp32, so a tile
+# GEMM equals the full GEMM bitwise whichever order the CPU's GEMM sums in
+x = jnp.asarray(rng.integers(-4, 5, (B, S, D)), jnp.float32)
+w1 = jnp.asarray(rng.integers(-4, 5, (D, F)), jnp.float32)
+w2 = jnp.asarray(rng.integers(-4, 5, (F, D)), jnp.float32)
+mesh = make_mesh((T,), ("model",))
 ctx = RingCtx("model", T)
 
 def fwd(xl, w1l, w2l):
@@ -166,6 +169,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.tensor_parallel import make_tp_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = {cfg}
 shape = InputShape("t", 16, 8, "train")
@@ -174,7 +178,7 @@ batch = {{k: jnp.asarray(v) for k, v in ds.batch(0).items()}}
 Z = 1e-4   # nonzero: the z_loss threading through cross_entropy_vp matters
 
 for mesh_shape in [(1, 2), (2, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     plan = ParallelPlan(remat="none", compute_dtype="float32", tp=2,
                         tp_impl="overlap", moe_dispatch={dispatch!r})
     model = build_model(cfg, plan, mesh, ("data",))
@@ -253,6 +257,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -267,7 +272,7 @@ ref_loss, _ = make_loss_fn(model, Hyper(z_loss=Z))(params, batch)
 ref_g = jax.grad(lambda p, b: make_loss_fn(model, Hyper(z_loss=Z))(p, b)[0])(
     params, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 for sched in ("gpipe", "1f1b"):
     plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, tp=2,
                         microbatches=4, pp_schedule=sched, tp_impl="overlap")
@@ -295,6 +300,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tmoe", Family.MOE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=0, vocab=128,
@@ -315,7 +321,7 @@ mb = {k: v.reshape((M, v.shape[0] // M) + v.shape[1:]) for k, v in batch.items()
 ref = np.mean([float(lf(params, {k: v[i] for k, v in mb.items()})[0])
                for i in range(M)])
 
-mesh = jax.make_mesh((2, 1, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 1, 2), ("pod", "data", "model"))
 for sched in ("gpipe", "1f1b"):
     plan = ParallelPlan(remat="none", compute_dtype="float32", pp=2, tp=2,
                         microbatches=M, pp_schedule=sched, tp_impl="overlap")
@@ -340,13 +346,14 @@ from repro.models import build_model
 from repro.optim import adamw_init
 from repro.train import Hyper, TrainState, make_train_step
 from repro.train.tensor_parallel import make_tp_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=2, d_ff=128, vocab=128)
 shape = InputShape("t", 16, 8, "train")
 ds = SyntheticDataset(cfg, shape)
 batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 hyper = Hyper(peak_lr=1e-3, total_steps=10, z_loss=1e-4)
 
 plan_g = ParallelPlan(remat="none", compute_dtype="float32", tp=2, zero_stage=1)

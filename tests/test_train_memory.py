@@ -30,6 +30,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, make_loss_fn
 from repro.train.pipeline import pipelined_loss_fn
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=4, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -45,7 +46,7 @@ hyper = Hyper(z_loss=Z)
 ref_loss, _ = make_loss_fn(model, hyper)(params, batch)
 ref_g = jax.grad(lambda p, b: make_loss_fn(model, hyper)(p, b)[0])(params, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 mems, grads = {}, {}
 for sched in ("gpipe", "1f1b"):
     # M = 4 = 2·P microbatches: the acceptance point for the memory claim
@@ -154,6 +155,7 @@ from repro.data import SyntheticDataset
 from repro.models import build_model
 from repro.train import Hyper, TrainState, init_train_state, make_train_step
 from repro.optim import adamw_init
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=4, d_ff=128, vocab=128)
@@ -169,7 +171,7 @@ s0 = init_train_state(m0, jax.random.PRNGKey(0))
 ref_state, ref_metrics = jax.jit(make_train_step(m0, plan0, hyper))(s0, batch)
 
 # ZeRO-1 on a (data=2, model=2) mesh, same microbatching
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 plan = ParallelPlan(remat="none", compute_dtype="float32", zero_stage=1,
                     microbatches=4)
 m1 = build_model(cfg, plan, mesh, ("data",))
@@ -198,3 +200,35 @@ mu_wq = new_state.opt.mu["layers"]["attn"]["wq"]
 assert not mu_wq.sharding.is_fully_replicated, mu_wq.sharding
 print("moments data-sharded OK:", mu_wq.sharding.spec)
 """)
+
+
+def test_mesh_state_layout_needs_one_compile(multidevice):
+    """init_train_state places the state on a mesh exactly as the jitted step
+    returns it (canonical specs, a mesh-replicated step counter), so the
+    second step reuses the first step's executable: on a chip each extra
+    compile of a real model costs tens of seconds."""
+    multidevice("""
+import jax, jax.numpy as jnp
+from repro.core import Family, InputShape, ModelConfig, ParallelPlan
+from repro.data import SyntheticDataset
+from repro.launch.mesh import make_mesh
+from repro.models import build_model
+from repro.train import Hyper, init_train_state, make_train_step
+
+cfg = ModelConfig("tiny", Family.DENSE, n_layers=2, d_model=64, n_heads=4,
+                  n_kv_heads=2, d_ff=128, vocab=128)
+ds = SyntheticDataset(cfg, InputShape("t", 16, 8, "train"))
+batch = {k: jnp.asarray(v) for k, v in ds.batch(0).items()}
+mesh = make_mesh((2, 2), ("data", "model"))
+for impl in ("gspmd", "overlap"):
+    plan = ParallelPlan(remat="none", compute_dtype="float32", tp=2,
+                        zero_stage=1, tp_impl=impl)
+    model = build_model(cfg, plan, mesh, ("data",))
+    state = init_train_state(model, jax.random.PRNGKey(0), mesh=mesh,
+                             plan=plan)
+    step = jax.jit(make_train_step(model, plan, Hyper(), mesh=mesh))
+    for _ in range(3):
+        state, _ = step(state, batch)
+    assert step._cache_size() == 1, (impl, step._cache_size())
+print("ONE_COMPILE_OK")
+""", n_devices=4)
