@@ -1,0 +1,19 @@
+"""ssd_roofline (%): the least time of every ssd_fwd and ssd_bwd call in the
+traced window (the larger of its FLOPs over the bf16 peak and its bytes over
+the HBM bandwidth, bench/flops.py) over their summed device time."""
+
+from bench import flops
+from bench import trace as T
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    tr, pk = run.cell.traffic, run.peaks
+    parts = []
+    for name, (f, b) in flops.kernel_costs(run.cell.config, tr["batch"],
+                                           tr["seq"]).items():
+        if name.startswith("ssd_"):
+            least = max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"])
+            parts.append((T.kernel_events(run.trace, name), least))
+    return T.roofline_share(parts)
