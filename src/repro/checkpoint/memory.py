@@ -54,7 +54,7 @@ import jax
 import numpy as np
 
 from .store import (CorruptCheckpointError, _checksum, _crc32,
-                    _flatten_with_names, _leaf_shards, _plan_meta,
+                    _flatten_with_names, _leaf_shards, _plan_meta, _span,
                     layout_diffs)
 
 
@@ -90,30 +90,34 @@ class MemoryCheckpointTier:
         neighbor's mirror. The oldest entry is evicted *before* the new one
         is built, so host RAM never holds ``keep + 1`` snapshots: at full
         model size that extra copy (twice the state with mirrors) is what
-        exhausts a host's RAM.
+        exhausts a host's RAM. Spans: ``mem.copy`` (device to host),
+        ``mem.checksum`` (the digests), ``mem.mirror`` (the ring rotation).
         """
-        t0 = time.time()
+        t0 = time.perf_counter()
         while len(self._ring) >= self.keep:
             self._ring.popleft()
         named = _flatten_with_names(tree)
+        with _span("mem.copy", step) as copied:
+            leaves = [_leaf_shards(x, copy=True) for _, x in named]
         primary: Dict[int, Dict[str, np.ndarray]] = \
             {g: {} for g in range(self.groups)}
         shard_meta: List[List[Dict[str, Any]]] = []
         counter = 0
-        for i, (_, x) in enumerate(named):
-            shards = _leaf_shards(x, copy=True)
-            metas = []
-            for j, (idx, a) in enumerate(shards):
-                key = f"a{i}" if len(shards) == 1 else f"a{i}_s{j}"
-                home = counter % self.groups
-                counter += 1
-                primary[home][key] = a
-                metas.append({"key": key, "index": idx,
-                              "checksum": _checksum(a), "crc32": _crc32(a),
-                              "dtype": str(a.dtype),
-                              "shape": [int(d) for d in a.shape],
-                              "home": home})
-            shard_meta.append(metas)
+        with _span("mem.checksum", step) as digest:
+            for i, shards in enumerate(leaves):
+                metas = []
+                for j, (idx, a) in enumerate(shards):
+                    key = f"a{i}" if len(shards) == 1 else f"a{i}_s{j}"
+                    home = counter % self.groups
+                    counter += 1
+                    primary[home][key] = a
+                    metas.append({"key": key, "index": idx,
+                                  "checksum": _checksum(a),
+                                  "crc32": _crc32(a),
+                                  "dtype": str(a.dtype),
+                                  "shape": [int(d) for d in a.shape],
+                                  "home": home})
+                shard_meta.append(metas)
         manifest = {
             "step": int(step),
             "names": [n for n, _ in named],
@@ -126,20 +130,24 @@ class MemoryCheckpointTier:
         }
         mirror: Dict[int, Dict[str, np.ndarray]] = \
             {g: {} for g in range(self.groups)}
-        if self.peer_redundancy and self.groups > 1:
-            # ring rotation: group g's bytes also live on (g+1) % groups —
-            # host-side stand-in for the fleet's ring ppermute of shard
-            # buffers (owned copies, so they survive lose_group(g))
-            for g in range(self.groups):
-                dst = (g + 1) % self.groups
-                for key, a in primary[g].items():
-                    mirror[dst][key] = np.array(a, copy=True)
+        with _span("mem.mirror", step) as rotate:
+            if self.peer_redundancy and self.groups > 1:
+                # ring rotation: group g's bytes also live on (g+1) % groups
+                # — host-side stand-in for the fleet's ring ppermute of
+                # shard buffers (owned copies, so they survive lose_group(g))
+                for g in range(self.groups):
+                    dst = (g + 1) % self.groups
+                    for key, a in primary[g].items():
+                        mirror[dst][key] = np.array(a, copy=True)
         self._ring.append({"manifest": manifest, "primary": primary,
                            "mirror": mirror})
-        self.snapshot_seconds = time.time() - t0
+        self.snapshot_seconds = time.perf_counter() - t0
         if self.flight is not None:
             self.flight.record("ckpt.persist", step, tier="memory",
                                seconds=self.snapshot_seconds,
+                               copy_seconds=copied.seconds,
+                               checksum_seconds=digest.seconds,
+                               mirror_seconds=rotate.seconds,
                                groups=self.groups,
                                mirrored=self.peer_redundancy)
 
@@ -229,9 +237,11 @@ class MemoryCheckpointTier:
         ``ValueError`` on a layout mismatch (e.g. after a remesh) — the
         recovery driver catches both and falls to the disk walk.
         ``self.last_rebuild`` reports how many shards came from peer
-        mirrors (0 ⇒ pure fast path).
+        mirrors (0 ⇒ pure fast path). Spans, one of each per leaf:
+        ``mem.fetch`` (the shards' host bytes, mirror rebuild and digest
+        checks included) and ``mem.put`` (host to device).
         """
-        t0 = time.time()
+        t0 = time.perf_counter()
         self.last_rebuild = 0
         e = self._entry(step)
         man = e["manifest"]
@@ -243,26 +253,32 @@ class MemoryCheckpointTier:
         named = _flatten_with_names(tree_like)
         assert [n for n, _ in named] == man["names"], \
             "memory checkpoint tree structure mismatch"
+        parts: Dict[str, float] = {}       # seconds of each span, summed
         leaves = []
         for metas, shape, dt, (_, l) in zip(
                 man["shards"], man["shapes"], man["dtypes"], named):
-            if len(metas) == 1:
-                full = self._fetch(e, metas[0], verify)
-            else:
-                full = np.zeros(shape, dtype=np.dtype(dt))
-                for m in metas:
-                    sl = tuple(slice(a, b) for a, b in m["index"])
-                    full[sl] = self._fetch(e, m, verify)
-            arr = jax.numpy.asarray(full, dtype=getattr(l, "dtype", None)
-                                    or full.dtype)
-            if isinstance(l, jax.Array) and getattr(l, "committed", False):
-                arr = jax.device_put(arr, l.sharding)
+            with _span("mem.fetch", man["step"], into=parts):
+                if len(metas) == 1:
+                    full = self._fetch(e, metas[0], verify)
+                else:
+                    full = np.zeros(shape, dtype=np.dtype(dt))
+                    for m in metas:
+                        sl = tuple(slice(a, b) for a, b in m["index"])
+                        full[sl] = self._fetch(e, m, verify)
+            with _span("mem.put", man["step"], into=parts):
+                arr = jax.numpy.asarray(full, dtype=getattr(l, "dtype", None)
+                                        or full.dtype)
+                if isinstance(l, jax.Array) and getattr(l, "committed",
+                                                        False):
+                    arr = jax.device_put(arr, l.sharding)
             leaves.append(arr)
         treedef = jax.tree_util.tree_structure(tree_like)
         tree = jax.tree_util.tree_unflatten(treedef, leaves)
-        self.restore_seconds = time.time() - t0
+        self.restore_seconds = time.perf_counter() - t0
         if self.flight is not None:
             self.flight.record("mem.restore", man["step"],
                                rebuilt_shards=self.last_rebuild,
-                               seconds=self.restore_seconds)
+                               seconds=self.restore_seconds,
+                               fetch_seconds=parts.get("mem.fetch", 0.0),
+                               put_seconds=parts.get("mem.put", 0.0))
         return man["step"], tree

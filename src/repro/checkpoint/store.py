@@ -90,6 +90,13 @@ def _inject():
     except ImportError:              # pragma: no cover - partial installs
         return None
 
+
+def _span(name: str, step: int, into: Optional[Dict[str, float]] = None):
+    """A :class:`repro.ft.flight.span` (imported on use, as in
+    :func:`_inject`: ``repro.ft`` imports this module)."""
+    from repro.ft.flight import span  # noqa: PLC0415
+    return span(name, step, into=into)
+
 # the ParallelPlan fields recorded in the manifest (impl/schedule knobs ride
 # along for forensics) ...
 PLAN_AXES = ("tp", "tp_impl", "cp", "cp_impl", "dp_shard", "zero_stage",
@@ -305,19 +312,16 @@ class CheckpointManager:
         Raises any failure from the *previous* save's background work.
         """
         self.wait()                                      # fence + raise errors
-        t0 = time.time()
-        named = _flatten_with_names(tree)
-        names = [n for n, _ in named]
-        cloned = None
-        if self.async_snapshot and not blocking:
-            cloned = self._cloner([x for _, x in named])
-        if cloned is not None:
-            # double-buffer path: stall = flatten + clone dispatch only
-            self.snapshot_seconds = time.time() - t0
-            host = None
-        else:
-            host = [(n, _leaf_shards(x)) for n, x in named]
-            self.snapshot_seconds = time.time() - t0
+        with _span("ckpt.snapshot", step) as snap:
+            named = _flatten_with_names(tree)
+            names = [n for n, _ in named]
+            cloned = None
+            if self.async_snapshot and not blocking:
+                cloned = self._cloner([x for _, x in named])
+            # double-buffer path: the stall is flatten + clone dispatch only
+            host = (None if cloned is not None
+                    else [(n, _leaf_shards(x)) for n, x in named])
+        self.snapshot_seconds = snap.seconds
 
         path = self.dir / f"ckpt_{step:08d}"
         mesh_axes = dict(mesh.shape) if mesh is not None else None
@@ -328,29 +332,30 @@ class CheckpointManager:
         def _snapshot_and_persist():
             nonlocal host
             if host is None:
-                t1 = time.time()
-                host = [(n, _leaf_shards(x, copy=False))
-                        for n, x in zip(names, cloned)]
-                self.d2h_seconds = time.time() - t1
-            t1 = time.time()
+                with _span("ckpt.snapshot", step) as d2h:
+                    host = [(n, _leaf_shards(x, copy=False))
+                            for n, x in zip(names, cloned)]
+                self.d2h_seconds = d2h.seconds
+            t1 = time.perf_counter()
             arrays = {}
             shard_meta = []
-            for i, (_, shards) in enumerate(host):
-                keys = []
-                for j, (idx, a) in enumerate(shards):
-                    # single-shard leaves keep the legacy "a{i}" key
-                    key = f"a{i}" if len(shards) == 1 else f"a{i}_s{j}"
-                    arrays[key] = a
-                    # sha256 prefix (legacy) + CRC32 + dtype/shape digests:
-                    # restore verifies all of them, so a flipped bit, a
-                    # truncated member, or a silently retyped array all
-                    # surface as CorruptCheckpointError
-                    keys.append({"key": key, "index": idx,
-                                 "checksum": _checksum(a),
-                                 "crc32": _crc32(a),
-                                 "dtype": str(a.dtype),
-                                 "shape": [int(d) for d in a.shape]})
-                shard_meta.append(keys)
+            with _span("ckpt.checksum", step) as digest:
+                for i, (_, shards) in enumerate(host):
+                    keys = []
+                    for j, (idx, a) in enumerate(shards):
+                        # single-shard leaves keep the legacy "a{i}" key
+                        key = f"a{i}" if len(shards) == 1 else f"a{i}_s{j}"
+                        arrays[key] = a
+                        # sha256 prefix (legacy) + CRC32 + dtype/shape
+                        # digests: restore verifies all of them, so a
+                        # flipped bit, a truncated member, or a silently
+                        # retyped array all surface as CorruptCheckpointError
+                        keys.append({"key": key, "index": idx,
+                                     "checksum": _checksum(a),
+                                     "crc32": _crc32(a),
+                                     "dtype": str(a.dtype),
+                                     "shape": [int(d) for d in a.shape]})
+                    shard_meta.append(keys)
             manifest = {
                 "step": step,
                 "names": names,
@@ -362,12 +367,14 @@ class CheckpointManager:
                 "mesh_axes": mesh_axes,
                 "time": time.time(),
             }
-            self._persist_with_retry(step, path, arrays, manifest)
-            self.persist_seconds = time.time() - t1
+            with _span("ckpt.write", step):
+                self._persist_with_retry(step, path, arrays, manifest)
+            self.persist_seconds = time.perf_counter() - t1
             if self.flight is not None:
                 self.flight.record("ckpt.persist", step, tier="disk",
                                    seconds=self.persist_seconds,
-                                   snapshot_seconds=self.snapshot_seconds)
+                                   snapshot_seconds=self.snapshot_seconds,
+                                   checksum_seconds=digest.seconds)
             self._gc()
 
         def _bg():
@@ -423,13 +430,14 @@ class CheckpointManager:
         errors (NFS blips, injected persist_exc) are retried up to
         ``io_retries`` times with delays ``io_backoff * 2^k``, bounded by the
         cumulative ``io_timeout`` deadline; the final failure propagates."""
-        deadline = time.time() + self.io_timeout
+        deadline = time.perf_counter() + self.io_timeout
         delay = self.io_backoff
         for attempt in range(1, self.io_retries + 1):
             try:
                 return self._persist_once(step, path, arrays, manifest)
             except Exception as e:
-                if attempt >= self.io_retries or time.time() + delay > deadline:
+                if (attempt >= self.io_retries
+                        or time.perf_counter() + delay > deadline):
                     if self.flight is not None:
                         self.flight.record("ckpt.persist_fail", step,
                                            attempts=attempt, error=repr(e))

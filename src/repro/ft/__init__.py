@@ -9,7 +9,9 @@
   snapshot within a grace budget, ``PREEMPTED`` marker, clean resumable
   exit;
 - :mod:`repro.ft.flight` — the crash flight recorder: a bounded ring of
-  per-step events dumped to JSON on preemption/crash/RecoveryExhausted;
+  per-step events dumped to JSON on preemption/crash/RecoveryExhausted, and
+  ``span``, which times each section of the loop and of a checkpoint save
+  on the profiler's clock;
 - :mod:`repro.ft.inject` — deterministic seeded fault injection at named
   fault points (the registry is ``inject.FAULT_POINTS``; see that module's
   docstring for how to add a point);

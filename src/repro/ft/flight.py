@@ -18,7 +18,22 @@ the moment something goes wrong:
   fault that fired (:mod:`repro.ft.inject`), and ``"preempt"`` events;
 - :class:`repro.checkpoint.store.CheckpointManager` and
   :class:`repro.checkpoint.memory.MemoryCheckpointTier` log checkpoint/tier
-  events (saves, persist failures, GC evictions, verify-before-evict skips).
+  events (saves, persist failures, GC evictions, verify-before-evict skips),
+  each save with the seconds of its parts (``checksum_seconds``, and for
+  the RAM tier ``copy_seconds``/``mirror_seconds``; ``mem.restore`` with
+  ``fetch_seconds``/``put_seconds``);
+- the loop writes one ``"loop"`` event per step whose ``seconds`` maps each
+  of its sections (``train.fetch``, ``train.inject``, ``train.step``,
+  ``train.readback``, ``train.monitor``, ``train.straggler``,
+  ``train.ckpt``, ``train.mem_ckpt``, ``train.restore``) to the host
+  seconds it took, and one ``"setup"`` event for the step-0 save (or the
+  resume's restore): the place to look for where a step's host time went.
+
+Those sections and the tiers' parts are :class:`span` s: one
+``jax.profiler.TraceAnnotation`` each, so a profiler trace shows them on the
+device trace's clock over the ops they cover, timed once on
+``time.perf_counter``. Every duration here is on that clock; only the dump's
+``wall_time`` is a wall timestamp.
 
 The ring is bounded (``maxlen``, knob ``RecoveryPolicy.flight_len``) so a
 month-long run carries a constant-size black box. ``dump()`` writes
@@ -36,6 +51,8 @@ import time
 from collections import deque
 from pathlib import Path
 from typing import Any, Dict, Optional
+
+import jax
 
 
 def _jsonable(v: Any) -> Any:
@@ -57,6 +74,52 @@ def _jsonable(v: Any) -> Any:
         return repr(v)
 
 
+class span:
+    """One section of the trainer, on the profiler's clock and the host's.
+
+    ``with span(name, step) as sp:`` opens
+    ``jax.profiler.TraceAnnotation(name, step=step)``, so a traced run shows
+    the section over the device ops it covers, and times it once with
+    ``time.perf_counter``: ``sp.seconds`` after the block, also added to
+    ``into[name]`` when ``into`` is given. With a
+    :class:`repro.ft.straggler.StragglerTimer` and one of its ``section``
+    names, the armed ``slow`` fault of that section sleeps inside the span
+    and the detector is fed the span's own seconds. With the profiler off
+    the annotation records nothing, and a span costs a few microseconds of
+    host time.
+    """
+
+    __slots__ = ("name", "step", "into", "straggler", "section", "rank",
+                 "seconds", "_ann", "_t0")
+
+    def __init__(self, name: str, step: int,
+                 into: Optional[Dict[str, float]] = None, straggler=None,
+                 section: Optional[str] = None, rank: Optional[int] = None):
+        self.name, self.step, self.into = name, int(step), into
+        self.straggler, self.section, self.rank = straggler, section, rank
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann = jax.profiler.TraceAnnotation(self.name, step=self.step)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            if self.straggler is not None:
+                self.straggler.slow_sleep(self.section, self.step, self.rank)
+            self.seconds = time.perf_counter() - self._t0
+        finally:
+            self._ann.__exit__(*exc)
+        if self.into is not None:
+            self.into[self.name] = self.into.get(self.name, 0.0) + self.seconds
+        if self.straggler is not None:
+            self.straggler.observe(self.section, self.step, self.seconds,
+                                   self.rank)
+        return False
+
+
 class FlightRecorder:
     """Bounded ring of structured events + atomic JSON dump.
 
@@ -72,10 +135,10 @@ class FlightRecorder:
         self.path = str(path) if path is not None else None
         self.events: deque = deque(maxlen=self.maxlen)
         self.dumped_path: Optional[str] = None
-        self._t0 = time.time()
+        self._t0 = time.perf_counter()
 
     def record(self, kind: str, step: int, **data: Any) -> None:
-        self.events.append({"t": time.time() - self._t0, "kind": kind,
+        self.events.append({"t": time.perf_counter() - self._t0, "kind": kind,
                             "step": int(step), **data})
 
     def dump(self, reason: str, path: Optional[str] = None,
@@ -89,7 +152,7 @@ class FlightRecorder:
         payload = {
             "reason": reason,
             "wall_time": time.time(),
-            "run_seconds": time.time() - self._t0,
+            "run_seconds": time.perf_counter() - self._t0,
             "n_events": len(self.events),
             "maxlen": self.maxlen,
             "extra": _jsonable(extra or {}),

@@ -54,8 +54,8 @@ Two anomaly kinds originate outside the Monitor's statistical detectors
   defaults to ignore (training continues on the older checkpoint cadence);
   ``"rollback"`` forces an immediate restore instead.
 - **straggler** — a confirmed fail-slow attribution from the attached
-  :class:`repro.ft.straggler.StragglerTimer`: the driver times the batch
-  fetch, the jitted step, and checkpoint persists, feeds the timer every
+  :class:`repro.ft.straggler.StragglerTimer`: the loop's spans of the
+  batch fetch, the jitted step, and checkpoint saves feed the timer every
   step, and notes the top confirmed ``(rank, section, class)`` event when
   the statistical detectors stayed quiet. Routed through
   ``policy.straggler`` (default ignore — attribution is always logged; the
@@ -81,6 +81,16 @@ walk, newest-intact first with full integrity verification, taking
 cleared on remesh (its recorded layouts are stale) and is not consulted for
 cross-layout restores — elasticity is the disk tier's job.
 
+**Spans**: every section of a step runs in a
+:class:`repro.ft.flight.span` named ``train.<section>`` — ``fetch``,
+``inject``, ``step`` (the step call through ``block_until_ready``),
+``readback`` (the metrics' host reads), ``monitor`` (detectors and flight
+records), ``straggler``, ``ckpt`` (the disk save as the loop waits for it),
+``mem_ckpt`` and ``restore``. A profiler trace shows them over the device
+ops; the flight recorder gets one ``"loop"`` event per step with each
+section's seconds, and one ``"setup"`` event for the step-0 save or the
+resume's restore. ``RunReport.step_seconds`` are the ``train.step`` spans.
+
 **Exit discipline**: the checkpoint manager is flushed (``ckpt.wait()``) in
 a ``finally`` on *every* exit path, and when a
 :class:`repro.ft.flight.FlightRecorder` is attached its ring is dumped to
@@ -92,8 +102,6 @@ black box.
 from __future__ import annotations
 
 import dataclasses
-import time
-from contextlib import nullcontext
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -102,8 +110,12 @@ from repro.checkpoint.store import CheckpointManager, CorruptCheckpointError
 from repro.core.config import RecoveryPolicy
 from . import inject as _inject
 from .anomaly import Anomaly, Monitor
+from .flight import span
 from .preempt import choose_tier, clear_marker, read_marker, write_marker
 from .straggler import choose_pp_layout, effective_layout
+
+# the loop's sections that the straggler detector attributes, by its names
+_STRAGGLER_SECTIONS = {"fetch": "data.fetch", "ckpt": "ckpt.persist"}
 
 
 class RecoveryExhausted(RuntimeError):
@@ -204,13 +216,14 @@ def run_with_recovery(
     :class:`RemeshSpec` the run continues under.
 
     ``straggler`` (a :class:`repro.ft.straggler.StragglerTimer`) turns on
-    fail-slow attribution: the driver times the batch fetch
-    (``data.fetch``), each checkpoint persist (``ckpt.persist``), and the
-    jitted step, and calls ``straggler.after_step`` every step — which also
-    executes any armed ``slow`` fault's real delay, so injected fail-slow
-    costs wall clock. A confirmed attribution is noted as a ``straggler``
-    anomaly and routed through ``policy.straggler``. ``rebalance(layout)``
-    is the mitigation hook: given the :func:`choose_pp_layout` target it
+    fail-slow attribution: the loop's spans time the batch fetch
+    (``data.fetch``), each checkpoint save (``ckpt.persist``), and the
+    jitted step, and it calls ``straggler.after_step`` every step — which
+    also executes any armed ``slow`` fault's real delay, so injected
+    fail-slow costs wall clock. A confirmed attribution is noted as a
+    ``straggler`` anomaly and routed through ``policy.straggler``.
+    ``rebalance(layout)`` is the mitigation hook: given the
+    :func:`choose_pp_layout` target it
     returns a :class:`RemeshSpec` for the same mesh with
     ``plan.pp_layout = layout``; the driver reshard-restores onto it
     exactly like a remesh. Without the hook (or for non-stage attributions)
@@ -277,6 +290,7 @@ def run_with_recovery(
     spike_counts: Dict[int, int] = {}
     rescue_mode: Dict[int, str] = {}   # step -> "rescue" | "skip", sticky
     step = 0
+    secs: Dict[str, float] = {}        # span seconds of the current turn
 
     def _restore(template, shardings=None, the_plan=None, the_mesh=None):
         """Tiered restore — memory first, then the verified disk walk.
@@ -285,6 +299,10 @@ def run_with_recovery(
         neighbor mirrors — both inside ``mem_ckpt.restore``). Tier 3: walk
         disk checkpoints newest-first, skipping any that fail integrity
         verification (the keep-last-K fallback)."""
+        with _sect("restore", step):
+            return _restore_tiers(template, shardings, the_plan, the_mesh)
+
+    def _restore_tiers(template, shardings, the_plan, the_mesh):
         nonlocal fallbacks, mem_restores
         if mem_ckpt is not None:
             try:
@@ -330,11 +348,21 @@ def run_with_recovery(
             return got, tree
         raise last_err                 # every checkpoint on disk is corrupt
 
-    def _sect(name, s):
-        """The straggler timer's section context (times + executes armed
-        ``slow`` delays), or a no-op when no timer is attached."""
-        return (straggler.section(name, s) if straggler is not None
-                else nullcontext())
+    def _sect(name, s) -> span:
+        """The ``train.<name>`` span of step ``s``, adding its seconds to
+        the turn's; it feeds the straggler timer where one is attached and
+        attributes the section."""
+        section = (_STRAGGLER_SECTIONS.get(name) if straggler is not None
+                   else None)
+        return span("train." + name, s, into=secs,
+                    straggler=straggler if section else None,
+                    section=section)
+
+    def _log(kind, s):
+        """One flight event with the seconds of each span since the last."""
+        if flight is not None and secs:
+            flight.record(kind, s, seconds=dict(secs))
+        secs.clear()
 
     def _try_save(s, st, blocking=False) -> Optional[Anomaly]:
         """Save, converting an (already retried) persist failure into a
@@ -342,7 +370,7 @@ def run_with_recovery(
         persist the failure of save N surfaces at save N+1's fence — the
         anomaly is stamped with the step the failure *surfaced* at."""
         try:
-            with _sect("ckpt.persist", s):
+            with _sect("ckpt", s):
                 ckpt.save(s, st, blocking=blocking, plan=plan, mesh=mesh)
             return None
         except (OSError, RuntimeError) as e:
@@ -352,7 +380,8 @@ def run_with_recovery(
 
     def _mem_save(s, st):
         if mem_ckpt is not None and s % max(1, mem_every) == 0:
-            mem_ckpt.save(s, st, plan=plan, mesh=mesh)
+            with _sect("mem_ckpt", s):
+                mem_ckpt.save(s, st, plan=plan, mesh=mesh)
 
     def _report(**over) -> RunReport:
         base = dict(steps_done=step, anomalies=monitor.anomalies,
@@ -378,9 +407,13 @@ def run_with_recovery(
     else:
         _try_save(step, state, blocking=True)
     _mem_save(step, state)
+    _log("setup", step)
+    turn = step                        # the step whose spans ``secs`` holds
 
     try:
         while step < n_steps:
+            _log("loop", turn)         # the turn before this one has ended
+            turn = step
             if preempt is not None and preempt.requested:
                 # graceful preemption: flush the in-flight persist first (a
                 # background failure must not pass for a durable
@@ -393,13 +426,15 @@ def run_with_recovery(
                     actions.append((step, "ckpt_io", policy.ckpt_io))
                 tier = choose_tier(preempt, ckpt, mem_ckpt)
                 if tier == "memory":
-                    mem_ckpt.save(step, state, plan=plan, mesh=mesh)
+                    with _sect("mem_ckpt", step):
+                        mem_ckpt.save(step, state, plan=plan, mesh=mesh)
                 else:
                     _try_save(step, state, blocking=True)
                 if flight is not None:
                     flight.record("preempt", step, tier=tier,
                                   signum=preempt.signum,
                                   grace_left=preempt.remaining())
+                _log("loop", turn)
                 fp = flight.dump("preempt") if flight is not None else None
                 write_marker(ckpt.dir, step, tier, preempt.signum, fp)
                 return state, _report(preempted=True, preempt_step=step,
@@ -417,33 +452,36 @@ def run_with_recovery(
             cur = state
             n_fired = len(_inject.CONTROLLER.fired)
             if fault_injector is not None:
-                cur = fault_injector(step, cur)
+                with _sect("inject", step):
+                    cur = fault_injector(step, cur)
             fn = (rescue_step if (mode == "rescue" and rescue_step)
                   else train_step)
             if fault_step_fn is not None:
                 faulty = fault_step_fn(step)
                 if faulty is not None:
                     fn = faulty
-            with _sect("data.fetch", step):
+            with _sect("fetch", step):
                 batch = get_batch(step)
-            t0 = time.perf_counter()
-            new_state, metrics = jax.block_until_ready(fn(cur, batch))
-            step_seconds = time.perf_counter() - t0
-            step_times.append(step_seconds)
-            loss = float(metrics["loss"])
-            gnorm = float(metrics.get("grad_norm", 0.0))
-            div = float(metrics.get("integrity_div", 0.0))
-            if flight is not None:
-                for point, kind, fstep in \
-                        _inject.CONTROLLER.fired[n_fired:]:
-                    flight.record("fault", step, point=point,
-                                  fault_kind=kind, armed_step=fstep)
-            anomaly = monitor.record(step, loss, gnorm)
-            if div != 0.0:
-                # replica checksum divergence outranks the statistical
-                # detectors: the step's own outputs cannot be trusted,
-                # whatever they look like
-                anomaly = monitor.note("sdc", step, f"integrity_div={div}")
+            with _sect("step", step) as step_span:
+                new_state, metrics = jax.block_until_ready(fn(cur, batch))
+            step_times.append(step_span.seconds)
+            with _sect("readback", step):
+                loss = float(metrics["loss"])
+                gnorm = float(metrics.get("grad_norm", 0.0))
+                div = float(metrics.get("integrity_div", 0.0))
+            with _sect("monitor", step):
+                if flight is not None:
+                    for point, kind, fstep in \
+                            _inject.CONTROLLER.fired[n_fired:]:
+                        flight.record("fault", step, point=point,
+                                      fault_kind=kind, armed_step=fstep)
+                anomaly = monitor.record(step, loss, gnorm)
+                if div != 0.0:
+                    # replica checksum divergence outranks the statistical
+                    # detectors: the step's own outputs cannot be trusted,
+                    # whatever they look like
+                    anomaly = monitor.note("sdc", step,
+                                           f"integrity_div={div}")
             if anomaly is not None and mode == "rescue" \
                     and anomaly.kind == "spike":
                 anomaly = None             # the rescue step owns this spike
@@ -455,7 +493,9 @@ def run_with_recovery(
             # outranks an attribution of the same symptom)
             ev = None
             if straggler is not None:
-                ev = straggler.after_step(step, step_seconds, plan=plan)
+                with _sect("straggler", step):
+                    ev = straggler.after_step(step, step_span.seconds,
+                                              plan=plan)
             if ev is not None and anomaly is None:
                 anomaly = monitor.note(
                     "straggler", step,
@@ -576,7 +616,9 @@ def run_with_recovery(
                     del losses[step:]
                     continue
             _mem_save(step, state)
+        _log("loop", turn)
     except BaseException as e:
+        _log("loop", turn)
         if flight is not None:
             # the autopsy artifact: dump the black box and pin its path on
             # the exception so the caller can find it without a report

@@ -47,11 +47,11 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Deque, Dict, List, Optional, Tuple
 
 from repro.core.config import ParallelPlan, RecoveryPolicy
 from . import inject as _inject
+from .flight import span
 
 # section -> component class of the attribution triple
 SECTION_CLASSES: Dict[str, str] = {
@@ -291,7 +291,9 @@ class StragglerTimer:
     Usage (the recovery driver wires this up):
 
     - wrap host-I/O work in :meth:`section` (``data.fetch`` around the batch
-      fetch, ``ckpt.persist`` around saves);
+      fetch, ``ckpt.persist`` around saves), or in a
+      :class:`repro.ft.flight.span` that names this timer, as the recovery
+      loop's ``train.fetch`` and ``train.ckpt`` spans do;
     - call :meth:`after_step` once per accepted step with the jitted step's
       measured wall time — it fans the step out into per-stage and per-ring
       shares (modeled from the plan's partition in this single-process
@@ -322,8 +324,8 @@ class StragglerTimer:
         self.detector = detector
         self._pending: List[Straggler] = []
 
-    def _slow_sleep(self, section: str, step: int, rank: Optional[int],
-                    units: float = 1.0) -> float:
+    def slow_sleep(self, section: str, step: int, rank: Optional[int],
+                   units: float = 1.0) -> float:
         """Execute (and return) the armed ``slow`` delay for this section's
         rank at this step: ``sleep_s`` per unit of work."""
         for point in SECTION_POINTS[section]:
@@ -334,20 +336,20 @@ class StragglerTimer:
                 return delay
         return 0.0
 
-    @contextmanager
-    def section(self, name: str, step: int, rank: Optional[int] = None):
-        """Time a host-side section (``data.fetch`` / ``ckpt.persist``),
-        executing any armed ``slow`` delay inside it; a confirmed event is
-        queued and surfaced by the next :meth:`after_step`."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._slow_sleep(name, step, rank)
-            dt = time.perf_counter() - t0
-            ev = self.detector.observe(name, rank, dt, step)
-            if ev is not None:
-                self._pending.append(ev)
+    def section(self, name: str, step: int,
+                rank: Optional[int] = None) -> span:
+        """Time a host-side section (``data.fetch`` / ``ckpt.persist``) as a
+        :class:`repro.ft.flight.span` that executes any armed ``slow`` delay
+        inside it and hands its seconds to :meth:`observe`."""
+        return span(name, step, straggler=self, section=name, rank=rank)
+
+    def observe(self, section: str, step: int, seconds: float,
+                rank: Optional[int] = None) -> None:
+        """Feed one host-side section's seconds to the detector; a confirmed
+        event is queued and surfaced by the next :meth:`after_step`."""
+        ev = self.detector.observe(section, rank, seconds, step)
+        if ev is not None:
+            self._pending.append(ev)
 
     def after_step(self, step: int, step_seconds: float,
                    plan: Optional[ParallelPlan] = None
@@ -361,7 +363,7 @@ class StragglerTimer:
             total = sum(layout)
             shares: Dict[int, float] = {}
             for r, n_l in enumerate(layout):
-                extra = self._slow_sleep("pp.stage", step, r, units=n_l)
+                extra = self.slow_sleep("pp.stage", step, r, units=n_l)
                 shares[r] = step_seconds * (n_l / total) + extra
             events.append(self.detector.observe_group(
                 "pp.stage", step, shares,
@@ -372,7 +374,7 @@ class StragglerTimer:
             if plan is not None and size > 1:
                 shares = {}
                 for r in range(size):
-                    extra = self._slow_sleep(section, step, r)
+                    extra = self.slow_sleep(section, step, r)
                     shares[r] = step_seconds / size + extra
                 events.append(
                     self.detector.observe_group(section, step, shares))
@@ -383,7 +385,7 @@ class StragglerTimer:
         step_ev = self.detector.observe("step.compute", None, step_seconds,
                                         step)
         events.append(step_ev)
-        k_extra = self._slow_sleep("kernel.dispatch", step, None)
+        k_extra = self.slow_sleep("kernel.dispatch", step, None)
         k_ev = self.detector.observe("kernel.dispatch", None,
                                      step_seconds + k_extra, step)
         if step_ev is None:

@@ -54,6 +54,11 @@ from .ssd_scan import ssd_chunk_scan
 
 IMPLS = ("auto", "xla", "pallas")
 
+# the name scope of the layout transposes into and out of each kernel: a
+# profile that keeps the ops' metadata finds their copies by it (forward,
+# remat recompute and backward)
+LAYOUT_SCOPE = "kernel_layout"
+
 
 def _tainted(point: str):
     """Route a dispatcher's primary output through a named fault point
@@ -200,13 +205,15 @@ def dispatch_attention_lse(q, k, v, *, impl: str = "auto", causal: bool = True,
     choice = select_impl(impl, head_dim=q.shape[-1], window=window,
                          q_offset=q_offset)
     if choice == "pallas":
+        with jax.named_scope(LAYOUT_SCOPE):
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         o, lse = flash_attention_lse(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, window=int(window),
+            q, k, v, causal=causal, window=int(window),
             softcap=softcap, scale=scale, q_offset=int(q_offset),
             block_q=block_q, block_k=block_k,
             interpret=resolve_interpret(interpret))
-        return o.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1)
+        with jax.named_scope(LAYOUT_SCOPE):
+            return o.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1)
     t = k.shape[1]
     if t <= 2 * block_size:
         return _layers.attention_direct_lse(
@@ -241,17 +248,17 @@ def dispatch_attention_chunk_bwd(q, k, v, do, lse, delta, *,
                          q_offset=q_offset)
     if choice == "pallas":
         hd = q.shape[-1]
+        with jax.named_scope(LAYOUT_SCOPE):
+            q, k, v, do = (a.transpose(0, 2, 1, 3) for a in (q, k, v, do))
+            lse, delta = lse.transpose(0, 2, 1), delta.transpose(0, 2, 1)
         dq, dk, dv = flash_attention_bwd(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), do.transpose(0, 2, 1, 3).astype(
-                jnp.float32),
-            lse.transpose(0, 2, 1), delta.transpose(0, 2, 1),
+            q, k, v, do.astype(jnp.float32), lse, delta,
             causal=causal, window=0, softcap=softcap,
             scale=float(scale) if scale is not None else hd ** -0.5,
             q_offset=int(q_offset), block_q=block_q, block_k=block_k,
             interpret=resolve_interpret(interpret))
-        return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-                dv.transpose(0, 2, 1, 3))
+        with jax.named_scope(LAYOUT_SCOPE):
+            return tuple(a.transpose(0, 2, 1, 3) for a in (dq, dk, dv))
     return _layers.attention_chunk_grads(
         q, k, v, do, lse, delta, causal=causal, window=0, softcap=softcap,
         q_offset=q_offset, scale=scale)
@@ -287,13 +294,15 @@ def dispatch_attention(q, k, v, *, impl: str = "auto", causal: bool = True,
     choice = select_impl(impl, head_dim=q.shape[-1], window=window,
                          q_offset=q_offset)
     if choice == "pallas":
+        with jax.named_scope(LAYOUT_SCOPE):
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         out = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, window=int(window),
+            q, k, v, causal=causal, window=int(window),
             softcap=softcap, scale=scale, q_offset=int(q_offset),
             block_q=block_q, block_k=block_k,
             interpret=resolve_interpret(interpret))
-        return out.transpose(0, 2, 1, 3)
+        with jax.named_scope(LAYOUT_SCOPE):
+            return out.transpose(0, 2, 1, 3)
 
     t = k.shape[1]
     if t <= 2 * block_size:
@@ -521,11 +530,13 @@ def dispatch_ssd_scan(x, dt, A, B, C, *, chunk: int, impl: str = "auto",
 
     choice = select_ssd_impl(impl, has_initial_state=initial_state is not None)
     if choice == "pallas":
-        y, state = ssd_chunk_scan(
-            x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
-            B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3), chunk=chunk,
-            interpret=resolve_interpret(interpret))
-        y = y.transpose(0, 2, 1, 3)
+        with jax.named_scope(LAYOUT_SCOPE):
+            x, dt = x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1)
+            B, C = B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3)
+        y, state = ssd_chunk_scan(x, dt, A, B, C, chunk=chunk,
+                                  interpret=resolve_interpret(interpret))
+        with jax.named_scope(LAYOUT_SCOPE):
+            y = y.transpose(0, 2, 1, 3)
     else:
         y, state = ssd_scan(x, dt, A, B, C, chunk=chunk,
                             initial_state=initial_state)

@@ -23,7 +23,10 @@ a just-in-time snapshot within ``--preempt-grace`` seconds, writes a
 ``PREEMPTED`` marker, and exits 0 — rerun with ``--resume`` to continue
 bit-identically. ``--flight-path`` arms the crash flight recorder: a
 bounded ring of per-step events dumped to JSON on preemption, crash, or
-recovery exhaustion for post-mortem attribution.
+recovery exhaustion for post-mortem attribution. Each step's ``"loop"``
+event carries the host seconds of each of the loop's sections
+(``train.fetch``, ``train.step``, ``train.readback``, ``train.monitor``,
+``train.ckpt``, ...), and the ``"setup"`` event those of the step-0 save.
 
 The step is compiled ahead of the loop, so compile time is reported apart
 from step time; :func:`main` returns a :class:`TrainResult` for callers that
@@ -154,7 +157,9 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainResult:
     ap.add_argument("--flight-path", default=None,
                     help="where the flight recorder dumps its JSON on "
                          "preemption/crash/exhaustion (default: "
-                         "<ckpt-dir>/flight.json)")
+                         "<ckpt-dir>/flight.json); each step's 'loop' event "
+                         "there holds the seconds of each section of the "
+                         "loop (train.fetch, train.step, train.ckpt, ...)")
     args = ap.parse_args(argv)
     use_compile_cache()
 

@@ -123,10 +123,19 @@ def _logits(params, x, cfg: ModelConfig, dtype, plan: Optional[ParallelPlan] = N
 
 
 def _embed(params, tokens, cfg: ModelConfig, dtype):
-    x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
-    if cfg.scale_embed:
-        x = x * jnp.asarray(np.sqrt(cfg.d_model), dtype)
-    return x
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
+        if cfg.scale_embed:
+            x = x * jnp.asarray(np.sqrt(cfg.d_model), dtype)
+        return x
+
+
+def _head(params, x, cfg: ModelConfig, dtype, aux):
+    """The final norm and the logits of a training forward, under the
+    ``head`` scope (the loss's cross-entropy joins it in ``train/step``)."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
+        return _logits(params, x, cfg, dtype), aux
 
 
 def _residual_constrainer(mesh, batch_axes):
@@ -257,8 +266,7 @@ def build_decoder_only(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
         body = _remat(body, plan.remat)
         (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
                                    (params["layers"], windows))
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-        return _logits(params, x, cfg, dtype), aux
+        return _head(params, x, cfg, dtype, aux)
 
     def init_cache(batch: int, max_seq: int):
         hkv, hd = cfg.n_kv_heads, cfg.head_dim
@@ -391,8 +399,7 @@ def build_ssm(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
 
         body = _remat(body, plan.remat)
         x, _ = jax.lax.scan(body, x, params["layers"])
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-        return _logits(params, x, cfg, dtype), jnp.float32(0.0)
+        return _head(params, x, cfg, dtype, jnp.float32(0.0))
 
     def init_cache(batch: int, max_seq: int):
         one = ssm_lib.init_ssm_cache(cfg, batch, dtype)
@@ -458,8 +465,10 @@ def build_hybrid(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
     def _ssm_layers(x, stacked, remat_mode):
         def body(xc, lp):
             xc = cx(xc)
-            h = rms_norm(xc, lp["norm1"]["scale"], cfg.rms_eps)
-            y = ssm_lib.ssm_block(lp["ssm"], h, cfg, dtype, plan=plan)
+            with jax.named_scope("norm"):
+                h = rms_norm(xc, lp["norm1"]["scale"], cfg.rms_eps)
+            with jax.named_scope("mixer"):
+                y = ssm_lib.ssm_block(lp["ssm"], h, cfg, dtype, plan=plan)
             y = checkpoint_name(y, "block_out")
             return xc + y, None
         x, _ = jax.lax.scan(_remat(body, remat_mode), x, stacked)
@@ -469,16 +478,21 @@ def build_hybrid(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
     cx = _residual_constrainer(mesh, batch_axes)
 
     def _shared_attn_fwd(sp, x, positions):
-        h = rms_norm(x, sp["norm1"]["scale"], cfg.rms_eps)
-        q, k, v = qkv_proj(sp["attn"], h, cfg, dtype)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        q, k, v = cq(q), ckv(k), ckv(v)
-        a = cq(attention(q, k, v, causal=True, window=cfg.sliding_window,
-                         impl=plan.attn_impl))
-        x = x + a.reshape(x.shape[0], x.shape[1], -1) @ sp["attn"]["wo"].astype(dtype)
-        h = rms_norm(x, sp["norm2"]["scale"], cfg.rms_eps)
-        return x + mlp_block(sp["mlp"], h, dtype)
+        with jax.named_scope("norm"):
+            h = rms_norm(x, sp["norm1"]["scale"], cfg.rms_eps)
+        with jax.named_scope("attn"):
+            q, k, v = qkv_proj(sp["attn"], h, cfg, dtype)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+            q, k, v = cq(q), ckv(k), ckv(v)
+            a = cq(attention(q, k, v, causal=True, window=cfg.sliding_window,
+                             impl=plan.attn_impl))
+            x = x + (a.reshape(x.shape[0], x.shape[1], -1)
+                     @ sp["attn"]["wo"].astype(dtype))
+        with jax.named_scope("norm"):
+            h = rms_norm(x, sp["norm2"]["scale"], cfg.rms_eps)
+        with jax.named_scope("mlp"):
+            return x + mlp_block(sp["mlp"], h, dtype)
 
     def forward(params, batch):
         tokens = batch["tokens"]
@@ -495,8 +509,7 @@ def build_hybrid(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
         x, _ = jax.lax.scan(group, x, head)
         if rest:
             x = _ssm_layers(x, tail, plan.remat)
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
-        return _logits(params, x, cfg, dtype), jnp.float32(0.0)
+        return _head(params, x, cfg, dtype, jnp.float32(0.0))
 
     def init_cache(batch: int, max_seq: int):
         one = ssm_lib.init_ssm_cache(cfg, batch, dtype)

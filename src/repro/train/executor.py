@@ -781,23 +781,30 @@ def decoder_layer(ctx: ParallelContext, cfg: ModelConfig, plan: ParallelPlan,
 
     def layer(x, lp, window, positions):
         x = ctx.cx(x)
-        h = rms_norm(x, lp["norm1"]["scale"], cfg.rms_eps)
-        a = attn_block(ctx, lp["attn"], h, cfg, positions=positions,
-                       window=window if alternating else cfg.sliding_window,
-                       dtype=dtype, impl=impl, collect_kv=collect_kv)
+        with jax.named_scope("norm"):
+            h = rms_norm(x, lp["norm1"]["scale"], cfg.rms_eps)
+        with jax.named_scope("attn"):
+            a = attn_block(ctx, lp["attn"], h, cfg, positions=positions,
+                           window=(window if alternating
+                                   else cfg.sliding_window),
+                           dtype=dtype, impl=impl, collect_kv=collect_kv)
         if collect_kv:
             a, kv = a
         a = checkpoint_name(a, "attn_out")
         if cfg.post_norm:
-            a = rms_norm(a, lp["norm1_post"]["scale"], cfg.rms_eps)
+            with jax.named_scope("norm"):
+                a = rms_norm(a, lp["norm1_post"]["scale"], cfg.rms_eps)
         x = x + a
-        h = rms_norm(x, lp["norm2"]["scale"], cfg.rms_eps)
-        if cfg.family == Family.MOE:
-            m, aux = moe_block_ex(ctx, lp["moe"], h, cfg, dtype, plan)
-        else:
-            m, aux = mlp_block_ex(ctx, lp["mlp"], h, dtype), jnp.float32(0.0)
-        if cfg.post_norm:
-            m = rms_norm(m, lp["norm2_post"]["scale"], cfg.rms_eps)
+        with jax.named_scope("norm"):
+            h = rms_norm(x, lp["norm2"]["scale"], cfg.rms_eps)
+        with jax.named_scope("mlp"):
+            if cfg.family == Family.MOE:
+                m, aux = moe_block_ex(ctx, lp["moe"], h, cfg, dtype, plan)
+            else:
+                m, aux = (mlp_block_ex(ctx, lp["mlp"], h, dtype),
+                          jnp.float32(0.0))
+            if cfg.post_norm:
+                m = rms_norm(m, lp["norm2_post"]["scale"], cfg.rms_eps)
         if collect_kv:
             return x + m, aux, kv
         return x + m, aux
@@ -810,8 +817,10 @@ def ssm_layer(ctx: ParallelContext, cfg: ModelConfig, plan: ParallelPlan,
     def layer(x, lp, window, positions):
         del window, positions
         x = ctx.cx(x)
-        h = rms_norm(x, lp["norm1"]["scale"], cfg.rms_eps)
-        y = ssm_block_ex(ctx, lp["ssm"], h, cfg, dtype, plan)
+        with jax.named_scope("norm"):
+            h = rms_norm(x, lp["norm1"]["scale"], cfg.rms_eps)
+        with jax.named_scope("mixer"):
+            y = ssm_block_ex(ctx, lp["ssm"], h, cfg, dtype, plan)
         y = checkpoint_name(y, "block_out")
         return x + y, jnp.float32(0.0)
     return layer

@@ -93,7 +93,8 @@ def _canonical(spec: P) -> P:
 def make_loss_fn(model: Model, hyper: Hyper) -> Callable:
     def loss_fn(params, batch):
         logits, aux = model.forward(params, batch)
-        loss = cross_entropy(logits, batch["labels"], z_loss=hyper.z_loss)
+        with jax.named_scope("head"):
+            loss = cross_entropy(logits, batch["labels"], z_loss=hyper.z_loss)
         return loss + aux, {"xent": loss, "moe_aux": aux}
     return loss_fn
 
@@ -147,7 +148,14 @@ def make_train_step(model: Model, plan: ParallelPlan,
                     mesh: Optional[Mesh] = None) -> Callable:
     loss_fn = (_overlap_loss_fn(model, plan, hyper, mesh)
                or make_loss_fn(model, hyper))
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
+
+    def grad_fn(params, batch):
+        # named scopes are op metadata only: a profiler trace splits the
+        # step into "loss" (forward, "jvp(loss)"; backward and remat
+        # recompute, "transpose(jvp(loss))"), "clip" and "optimizer"
+        with jax.named_scope("loss"):
+            return value_and_grad(params, batch)
     use_zero = (mesh is not None and plan.zero_stage >= 1
                 and "data" in mesh.shape and mesh.shape["data"] > 1)
 
@@ -186,16 +194,18 @@ def make_train_step(model: Model, plan: ParallelPlan,
             (loss, aux), grads = grad_fn(params, batch)
             grads = scatter(grads)
 
-        grads, gnorm = clip_by_global_norm(grads, hyper.grad_clip)
-        lr = cosine_schedule(opt.step, hyper.peak_lr, hyper.warmup_steps,
-                             hyper.total_steps)
-        if use_zero:
-            new_params, new_opt = adamw_update_sharded(
-                grads, opt, params, lr, mesh=mesh, param_specs=pspecs,
-                opt_specs=ospecs, weight_decay=hyper.weight_decay)
-        else:
-            new_params, new_opt = adamw_update(
-                grads, opt, params, lr, weight_decay=hyper.weight_decay)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, hyper.grad_clip)
+        with jax.named_scope("optimizer"):
+            lr = cosine_schedule(opt.step, hyper.peak_lr, hyper.warmup_steps,
+                                 hyper.total_steps)
+            if use_zero:
+                new_params, new_opt = adamw_update_sharded(
+                    grads, opt, params, lr, mesh=mesh, param_specs=pspecs,
+                    opt_specs=ospecs, weight_decay=hyper.weight_decay)
+            else:
+                new_params, new_opt = adamw_update(
+                    grads, opt, params, lr, weight_decay=hyper.weight_decay)
         metrics = {
             "loss": loss,
             "grad_norm": gnorm,
