@@ -35,3 +35,17 @@ def run_multidevice(script: str, n_devices: int = 8, timeout: int = 600):
 @pytest.fixture(scope="session")
 def multidevice():
     return run_multidevice
+
+
+@pytest.fixture
+def force_head_block(monkeypatch):
+    """Shrink the SSD kernels' VMEM budget until a pass takes ``hb`` heads a
+    grid step: ``force(hb, heads_per_group, p, n, chunk, backward=False)``."""
+    from repro.kernels import ssd_scan as S
+
+    def force(hb, heads_per_group, p, n, chunk, backward=False):
+        monkeypatch.setattr(S, "VMEM_BUDGET",
+                            S.vmem_bytes(hb, p, n, chunk, backward))
+        assert S.ssd_head_block(heads_per_group, p, n, chunk, backward) == hb
+
+    return force

@@ -81,20 +81,26 @@ def test_expert_gemm_allclose(case, dtype):
 
 
 SSD_CASES = [
-    # (b, l, h, p, g, n, chunk)
+    # (b, l, h, p, g, n, chunk[, head block forced by a small VMEM budget])
     (2, 64, 4, 8, 2, 16, 16),
     (1, 128, 2, 16, 1, 32, 32),
     (1, 96, 4, 8, 4, 8, 24),       # chunk not power of two
     (2, 32, 8, 4, 2, 8, 32),       # single chunk
+    (1, 64, 8, 8, 1, 16, 16),      # one group, one block of all 8 heads
+    (2, 64, 12, 8, 3, 8, 16, 2),   # g = 3, two head blocks per group
+    (1, 32, 8, 8, 2, 16, 32, 2),   # single chunk, two head blocks per group
+    (1, 48, 6, 8, 1, 8, 16, 3),    # blocks of 3 of 6 heads
 ]
 
 
 @pytest.mark.parametrize("case", SSD_CASES)
-def test_ssd_chunk_scan_allclose(case):
+def test_ssd_chunk_scan_allclose(case, force_head_block):
     """Fused SSD kernel vs the pure-jnp ssd_scan oracle (y and final state)."""
     from repro.kernels import ssd_chunk_scan
     from repro.models.ssm import ssd_scan
-    b, l, h, p, g, n, chunk = case
+    b, l, h, p, g, n, chunk, *forced = case
+    if forced:
+        force_head_block(forced[0], h // g, p, n, chunk)
     rng = np.random.default_rng(hash(case) % 2**32)
     x = _t(rng, (b, l, h, p), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, (b, l, h)), jnp.float32)
@@ -129,6 +135,65 @@ def test_ssd_kernel_state_carries_across_chunks():
     assert float(jnp.abs(y[:, :, :chunk]).max()) > 0
     assert float(jnp.abs(y2[:, :, :chunk]).max()) < 1e-6
     assert float(jnp.abs(y[:, :, chunk:] - y2[:, :, chunk:]).max()) > 1e-6
+
+
+# (heads per group the kernel sees, p, n, chunk): mamba2-370m (32 heads),
+# its local heads under tensor parallelism of 2 and 4, zamba2-1.2b (64
+# heads, N 64), a head count with an odd divisor
+HEAD_BLOCK_SHAPES = [(32, 64, 128, 128), (16, 64, 128, 128),
+                     (8, 64, 128, 128), (64, 64, 64, 128), (24, 64, 128, 128)]
+
+
+@pytest.mark.parametrize("shape", HEAD_BLOCK_SHAPES)
+@pytest.mark.parametrize("backward", [False, True])
+def test_ssd_head_block_fits_budget(shape, backward):
+    """The head block divides the heads per group, fits the VMEM budget, and
+    is the largest divisor that does."""
+    from repro.kernels import ssd_scan as S
+    hpg, p, n, chunk = shape
+    hb = S.ssd_head_block(hpg, p, n, chunk, backward)
+    assert hpg % hb == 0
+    assert S.vmem_bytes(hb, p, n, chunk, backward) <= S.VMEM_BUDGET
+    for bigger in range(hb + 1, hpg + 1):
+        if hpg % bigger == 0:
+            assert S.vmem_bytes(bigger, p, n, chunk, backward) > S.VMEM_BUDGET
+
+
+def _ssd_calls(b, h, l, p, n, chunk=128):
+    """{kernel name: (grid, output shapes)} of a forward and backward SSD call,
+    traced at these shapes (nothing runs)."""
+    from repro.kernels.ssd_scan import ssd_chunk_scan
+    sds = jax.ShapeDtypeStruct
+    args = [sds((b, h, l, p), jnp.bfloat16), sds((b, h, l), jnp.float32),
+            sds((h,), jnp.float32), sds((b, 1, l, n), jnp.bfloat16),
+            sds((b, 1, l, n), jnp.bfloat16)]
+
+    def fwd_bwd(*a):
+        (y, st), vjp = jax.vjp(
+            lambda *d: ssd_chunk_scan(*d, chunk=chunk, interpret=True), *a)
+        return vjp((jnp.ones_like(y), jnp.ones_like(st)))
+
+    calls = {}
+    for eqn in jax.make_jaxpr(fwd_bwd)(*args).jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] = (eqn.params["grid_mapping"].grid,
+                                         [v.aval.shape for v in eqn.outvars])
+    return calls
+
+
+def test_ssd_grid_at_mamba2_width():
+    """At mamba2-370m's shapes (8 x 2048 tokens, 32 heads in one group) each
+    kernel call runs one grid step per head block and chunk, and the
+    backward writes dB/dC once per head block, not per head."""
+    from repro.kernels.ssd_scan import ssd_head_block
+    calls = _ssd_calls(8, 32, 2048, 64, 128)
+    assert set(calls) == {"ssd_fwd", "ssd_bwd"}
+    for name, (grid, _) in calls.items():
+        hb = ssd_head_block(32, 64, 128, 128, backward=name == "ssd_bwd")
+        assert grid == (8, 32 // hb, 16)
+        assert int(np.prod(grid)) <= 256
+    grid, shapes = calls["ssd_bwd"]
+    assert shapes[3] == shapes[4] == (8, grid[1], 2048, 128)
 
 
 def test_expert_gemm_expert_isolation():

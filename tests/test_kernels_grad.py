@@ -88,17 +88,23 @@ def test_expert_gemm_group_sizes_zero_expert():
 
 
 SSD_GRAD_CASES = [
-    # (b, l, h, p, g, n, chunk)
+    # (b, l, h, p, g, n, chunk[, backward head block forced by a small budget])
     (1, 32, 2, 4, 1, 4, 8),
     (2, 48, 4, 8, 2, 8, 16),       # GQA-style g < h
     (1, 24, 4, 4, 2, 4, 24),       # single chunk, g < h
+    (1, 32, 8, 4, 1, 8, 8),        # one group, one block of all 8 heads
+    (2, 48, 8, 8, 2, 8, 16, 2),    # two head blocks per group, g = 2
+    (1, 16, 6, 4, 3, 4, 16, 1),    # single chunk, g = 3, one head a block
+    (1, 64, 6, 8, 1, 16, 16, 3),   # blocks of 3 of 6 heads
 ]
 
 
 @pytest.mark.parametrize("case", SSD_GRAD_CASES)
-def test_ssd_grad_matches_oracle(case):
+def test_ssd_grad_matches_oracle(case, force_head_block):
     from repro.kernels import ssd_chunk_scan
-    b, l, h, p, g, n, chunk = case
+    b, l, h, p, g, n, chunk, *forced = case
+    if forced:
+        force_head_block(forced[0], h // g, p, n, chunk, backward=True)
     rng = np.random.default_rng(abs(hash(case)) % 2**32)
     x = _rand(rng, (b, l, h, p))
     dt = jnp.asarray(rng.uniform(0.01, 0.2, (b, l, h)), jnp.float32)

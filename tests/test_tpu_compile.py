@@ -86,12 +86,15 @@ def _gemm(e=8, c=512, d=2048, f=1024):
 
 # published widths: qwen1.5-4b (20 heads, head dim 128), gemma2-9b (GQA 16/8,
 # head dim 256, window 4096, softcap 50), mamba2-370m (32 heads, P=64, N=128,
-# chunk 128), olmoe-1b-7b experts (d=2048, f=1024; 8 of its 64 experts)
+# chunk 128), zamba2-1.2b's Mamba-2 layers (64 heads, P=64, N=64, one group:
+# the head block's VMEM budget at twice the heads), olmoe-1b-7b experts
+# (d=2048, f=1024; 8 of its 64 experts)
 KERNELS = {
     "attention-qwen1.5-4b": lambda: _attention(20, 20, 128, 4096),
     "attention-gemma2-9b": lambda: _attention(16, 8, 256, 4096, window=4096,
                                               softcap=50.0),
     "ssd-mamba2-370m": _ssd,
+    "ssd-zamba2-1.2b": lambda: _ssd(h=64, n=64),
     "grouped-gemm-olmoe-1b-7b": _gemm,
 }
 
