@@ -3,6 +3,15 @@
 
 38L, d_model=2048, shared attention block (32 heads, kv=32, d_ff=8192) applied
 every 6 Mamba2 layers; ssm_state=64. Sub-quadratic: runs long_500k.
+
+This preset runs a simplified shared block, not the published one: a pre-norm
+residual attention + SwiGLU block on the d_model residual, every 6 layers.
+The published block (``models/families.build_zamba2``, run by ``zamba2-7b``)
+reads [h, embedding] 2·d_model wide, adds its output to the next Mamba-2
+layer's input, has a gated GELU MLP with per-application adapters and an
+output linear, and sits at ``hybrid_layer_ids``. Moving this preset onto it
+waits for its published config.json (the adapter settings stay assumed till
+then) and for the retirement of ``bench/configs/zamba2-1.2b.12l.*`` (ROADMAP).
 """
 
 from repro.core import Family, ModelConfig, SSMConfig, register

@@ -135,6 +135,7 @@ class SSMConfig:
     head_dim: int = 64
     n_groups: int = 1
     chunk: int = 128              # SSD chunk length
+    conv_bias: bool = False       # a bias on the depthwise conv's channels
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,6 +171,16 @@ class ModelConfig:
     # hybrid (zamba2): apply a weight-shared attention block every k ssm layers
     shared_attn_every: int = 0
 
+    # hybrid, the published Zamba2 block (transformers 4.57
+    # models/zamba2/modeling_zamba2.py): a non-empty ``hybrid_layer_ids``
+    # selects it and places it (``shared_attn_every`` is then not read).
+    # Application j, before Mamba-2 layer hybrid_layer_ids[j], runs shared
+    # block j % num_mem_blocks, then its own MLP adapter and output linear;
+    # ids at or beyond n_layers are left out with their layers.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0         # the per-application MLP adapter's rank
+
     # encoder-decoder (whisper)
     enc_layers: int = 0
     enc_frames: int = 1500        # audio frontend stub: frame-embedding count
@@ -183,6 +194,17 @@ class ModelConfig:
     def __post_init__(self):
         if self.n_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def shared_applications(self) -> Tuple[int, ...]:
+        """The layers (published Zamba2 block) the shared block runs before."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.n_layers)
+
+    @property
+    def shared_blocks(self) -> int:
+        """Shared blocks the published Zamba2 block holds: those its
+        applications use."""
+        return min(self.num_mem_blocks, len(self.shared_applications))
 
     @property
     def is_enc_dec(self) -> bool:
@@ -222,7 +244,7 @@ class ModelConfig:
             nh = di // self.ssm.head_dim
             ng, ns = self.ssm.n_groups, self.ssm.d_state
             in_proj = d * (2 * di + 2 * ng * ns + nh)
-            conv = (di + 2 * ng * ns) * self.ssm.d_conv
+            conv = (di + 2 * ng * ns) * (self.ssm.d_conv + self.ssm.conv_bias)
             out = di * d
             return in_proj + conv + out + 2 * nh  # + A_log, D
 
@@ -230,7 +252,14 @@ class ModelConfig:
             total += L * (ssm_params() + d)
         elif self.family == Family.HYBRID:
             total += L * (ssm_params() + d)
-            if self.shared_attn_every:
+            if self.hybrid_layer_ids:
+                hq, hkv = self.n_heads * hd, self.n_kv_heads * hd
+                block = (2 * d * (hq + 2 * hkv) + hq * d
+                         + mlp_params(self.d_ff) + 3 * d)
+                app = d * d + self.adapter_rank * (d + 2 * self.d_ff)
+                total += (self.shared_blocks * block
+                          + len(self.shared_applications) * app)
+            elif self.shared_attn_every:
                 total += attn_params() + 2 * d  # one shared block
         elif self.family == Family.MOE:
             per_layer = attn_params() + 2 * d

@@ -19,6 +19,7 @@ ARCH_IDS: List[str] = [
     "olmoe-1b-7b",
     "qwen2.5-14b",
     "zamba2-1.2b",
+    "zamba2-7b",
     "codeqwen1.5-7b",
     "gemma2-9b",
     "whisper-small",

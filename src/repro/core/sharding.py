@@ -61,7 +61,8 @@ _COL_KEYS = {"wq", "wk", "wv", "gate", "up", "wz", "wx", "wdt"}
 _ROW_KEYS = {"wo", "down", "out_proj"}
 _REPLICATED_KEYS = {"scale", "bias", "A_log", "D", "dt_bias", "bq", "bk", "bv",
                     "wB", "wC"}
-_CONV_KEYS = {"conv_x", "conv_B", "conv_C"}
+_CONV_KEYS = {"conv_x", "conv_B", "conv_C", "conv_bias_x", "conv_bias_B",
+              "conv_bias_C"}
 
 
 def _path_names(path) -> Tuple[str, ...]:

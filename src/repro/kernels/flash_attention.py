@@ -11,7 +11,8 @@ version (DESIGN.md §2) organizes around the grid + BlockSpec machinery:
 - BlockSpec index_maps implement GQA natively: query head h reads KV head
   h // group, so repeated KV never materializes in HBM.
 - block shapes default to 128 (MXU-aligned); the last dim (head_dim) is kept
-  whole inside VMEM (128/256 for all assigned archs).
+  whole inside VMEM (128, 224 or 256 for the assigned archs: a block that
+  spans the whole dim lowers at 224 too, with no padding).
 - causal + sliding-window + logit-softcap masks are computed from global tile
   offsets with iota (``q_offset`` shifts query positions for chunked prefill),
   and fully-masked tiles exit early via ``pl.when``.

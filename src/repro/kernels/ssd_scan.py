@@ -33,7 +33,8 @@ Layout (TPU adaptation — same pattern as flash_attention.py):
   under the 32-MiB scoped limit both kernels ask for (:func:`ssd_head_block`,
   :func:`vmem_bytes`). mamba2-370m (32 heads, P 64, N 128, q 128): one block
   of 32 heads in both passes, 128 grid steps a call at 8 x 2048 tokens;
-  zamba2-1.2b (64 heads, N 64): blocks of 32.
+  zamba2-1.2b (64 heads, N 64): blocks of 32; zamba2-7b's tensor-parallel
+  share (56 heads, N 64, chunk 256): 28 forward, 14 backward.
 
 Backward follows the FlashAttention-2 recipe (PAPERS.md): the forward
 additionally saves only the state *entering* each chunk — an (nc, p, n) strip

@@ -427,6 +427,8 @@ def build_ssm(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
 
 def build_hybrid(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
                  mesh=None, batch_axes=("data",)) -> Model:
+    if cfg.hybrid_layer_ids:
+        return build_zamba2(cfg, plan, mesh, batch_axes)
     plan = plan or ParallelPlan()
     dtype = jnp.dtype(plan.compute_dtype)
     every = cfg.shared_attn_every
@@ -569,6 +571,245 @@ def build_hybrid(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
             new_head, new_tail)
         logits = _logits(params, x, cfg, dtype)
         return logits, {"ssm": new_ssm, "attn_k": ks, "attn_v": vs}
+
+    return Model(cfg, init, forward, init_cache, decode_step)
+
+
+# ---------------------------------------------------------------------------
+# hybrid, the published Zamba2 block (transformers 4.57,
+# models/zamba2/modeling_zamba2.py: Zamba2HybridLayer,
+# Zamba2AttentionDecoderLayer, Zamba2Attention, Zamba2MLP)
+
+def _tree_slice(tree, lo: int, hi: int):
+    return jax.tree.map(lambda a: a[lo:hi], tree)
+
+
+def _tree_pick(tree, i: int):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _init_shared_block(cfg: ModelConfig):
+    d = cfg.d_model
+    d_in = 2 * d                  # the block reads [h, embedding]
+    hq, hkv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+
+    def one(rng):
+        r = split_tree(rng, 5)
+        return {
+            "norm1": {"scale": jnp.zeros((d_in,), jnp.float32)},
+            "norm2": {"scale": jnp.zeros((d,), jnp.float32)},
+            "attn": {"wq": dense_init(r[0], (d_in, hq)),
+                     "wk": dense_init(r[1], (d_in, hkv)),
+                     "wv": dense_init(r[2], (d_in, hkv)),
+                     "wo": dense_init(r[3], (hq, d))},
+            "mlp": init_mlp(r[4], d, cfg.d_ff),
+        }
+    return one
+
+
+def _init_application(cfg: ModelConfig):
+    """One application's own weights: the MLP adapter and the output linear
+    (Zamba2-7B has no attention adapter)."""
+    d, rank = cfg.d_model, cfg.adapter_rank
+
+    def one(rng):
+        r = split_tree(rng, 4)
+        return {"linear": {"w": dense_init(r[0], (d, d))},
+                "mlp_adapter": {"a": dense_init(r[1], (d, rank)),
+                                "gate": dense_init(r[2], (rank, cfg.d_ff)),
+                                "up": dense_init(r[3], (rank, cfg.d_ff))}}
+    return one
+
+
+def zamba2_attn_scale(cfg: ModelConfig) -> float:
+    """The published block scales scores by (hd/2)^-1/2: its heads are twice
+    d_model / n_heads wide because they read the concatenated input."""
+    return (cfg.head_dim / 2) ** -0.5
+
+
+def zamba2_attention(p, u, cfg: ModelConfig, dtype, positions, attend):
+    """The shared block's attention on its normed input u (B, S, 2·d):
+    ``a·W_o`` (B, S, d). ``attend(q, k, v)`` -> (B, S, Hq, hd)."""
+    b, s = u.shape[:2]
+    with jax.named_scope("attn"):
+        q, k, v = qkv_proj(p, u, cfg, dtype)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        a = attend(q, k, v)
+        t = a.reshape(b, s, -1) @ p["wo"].astype(dtype)
+        return checkpoint_name(t, "attn_out")
+
+
+def zamba2_mlp(p, ad, m, dtype):
+    """The shared block's gated GELU (erf) MLP on its normed input m
+    (B, S, d), the application's adapter ``ad`` added to the gate and up
+    projections."""
+    with jax.named_scope("mlp"):
+        g = m @ p["gate"].astype(dtype)
+        u = m @ p["up"].astype(dtype)
+        with jax.named_scope("adapter"):
+            low = m @ ad["a"].astype(dtype)
+            g = g + low @ ad["gate"].astype(dtype)
+            u = u + low @ ad["up"].astype(dtype)
+        return (jax.nn.gelu(g, approximate=False) * u) @ p["down"].astype(dtype)
+
+
+def build_zamba2(cfg: ModelConfig, plan: Optional[ParallelPlan] = None,
+                 mesh=None, batch_axes=("data",)) -> Model:
+    """Mamba-2 layers with the published Zamba2 shared block.
+
+    Application j, at layer i = ``hybrid_layer_ids[j]``, with block
+    b = j mod ``num_mem_blocks`` and e the embedding output:
+    ``u = RMSNorm([h, e])``; ``q, k, v = u·W_b``; rotary (rotate-half) on
+    q and k; ``a = causal softmax(q·kᵀ·(hd/2)^-1/2)·v``; ``t = a·W_o``;
+    ``m = RMSNorm(t)``; ``[g|p] = m·W_gu + (m·A_j)·B_j``;
+    ``t = (gelu_erf(g)·p)·W_down·Lin_j``; then
+    ``h ← h + Mamba_i(RMSNorm(h + t))``: the block's output enters the
+    Mamba-2 layer's input, not the residual.
+    The hybrid layer (application and Mamba-2 layer) is rematerialized as one
+    unit, and the Mamba-2 layers between applications run as scans.
+    """
+    plan = plan or ParallelPlan()
+    dtype = jnp.dtype(plan.compute_dtype)
+    apps = cfg.shared_applications
+    assert list(apps) == sorted(set(apps)), apps
+    n_blocks = cfg.shared_blocks
+    # (lo, hi, application) runs: Mamba-2 layers lo..hi-1, then the hybrid
+    # layer at hi when an application index is given
+    runs = [(0 if j == 0 else apps[j - 1] + 1, i, j)
+            for j, i in enumerate(apps)]
+    runs.append((apps[-1] + 1 if apps else 0, cfg.n_layers, None))
+    scale = zamba2_attn_scale(cfg)
+    cq, ckv = _seq_constrainers(plan, mesh, batch_axes)
+    cx = _residual_constrainer(mesh, batch_axes)
+
+    def init_layer(rng):
+        return {
+            "norm1": {"scale": jnp.zeros((cfg.d_model,), jnp.float32)},
+            "ssm": ssm_lib.init_ssm(rng, cfg),
+        }
+
+    def init(rng):
+        r = split_tree(rng, 5)
+        params = {
+            "embed": {"tok": dense_init(r[0], (_padded_vocab(cfg, plan), cfg.d_model), in_axis=-1)},
+            "layers": _stacked_init(r[1], cfg.n_layers, init_layer),
+            "final_norm": {"scale": jnp.zeros((cfg.d_model,), jnp.float32)},
+        }
+        if apps:
+            params["shared"] = _stacked_init(r[2], n_blocks,
+                                             _init_shared_block(cfg))
+            params["apps"] = _stacked_init(r[3], len(apps),
+                                           _init_application(cfg))
+        if not cfg.tie_embeddings:
+            params["lm_head"] = {"w": dense_init(r[4], (cfg.d_model, _padded_vocab(cfg, plan)))}
+        return params
+
+    def shared_block(bp, ap, x, emb, positions, attend):
+        """One application on (B, S, d) inputs: the block's output after the
+        application's linear. ``attend(q, k, v)`` runs the attention."""
+        with jax.named_scope("shared_block"):
+            with jax.named_scope("norm"):
+                u = rms_norm(jnp.concatenate([x, emb], -1),
+                             bp["norm1"]["scale"], cfg.rms_eps)
+            t = zamba2_attention(bp["attn"], u, cfg, dtype, positions, attend)
+            with jax.named_scope("norm"):
+                m = rms_norm(t, bp["norm2"]["scale"], cfg.rms_eps)
+            t = zamba2_mlp(bp["mlp"], ap["mlp_adapter"], m, dtype)
+            with jax.named_scope("adapter"):
+                return t @ ap["linear"]["w"].astype(dtype)
+
+    def mamba(x, lp, t=None):
+        """Mamba-2 layer ``x + Mamba(RMSNorm(x + t))``; t is an
+        application's output, or None."""
+        x = cx(x)
+        with jax.named_scope("norm"):
+            h = rms_norm(x if t is None else x + t, lp["norm1"]["scale"],
+                         cfg.rms_eps)
+        with jax.named_scope("mixer"):
+            y = ssm_lib.ssm_block(lp["ssm"], h, cfg, dtype, plan=plan)
+        return x + checkpoint_name(y, "block_out")
+
+    def attend(q, k, v):
+        return cq(attention(cq(q), ckv(k), ckv(v), causal=True, scale=scale,
+                            impl=plan.attn_impl))
+
+    def hybrid(x, emb, bp, ap, lp):
+        positions = jnp.arange(x.shape[1])
+        return mamba(x, lp, shared_block(bp, ap, x, emb, positions, attend))
+
+    def forward(params, batch):
+        x = _embed(params, batch["tokens"], cfg, dtype)
+        emb = x
+        body = _remat(lambda xc, lp: (mamba(xc, lp), None), plan.remat)
+        hybrid_layer = _remat(hybrid, plan.remat)
+        for lo, hi, j in runs:
+            if hi > lo:
+                x, _ = jax.lax.scan(body, x,
+                                    _tree_slice(params["layers"], lo, hi))
+            if j is not None:
+                x = hybrid_layer(x, emb,
+                                 _tree_pick(params["shared"], j % n_blocks),
+                                 _tree_pick(params["apps"], j),
+                                 _tree_pick(params["layers"], hi))
+        return _head(params, x, cfg, dtype, jnp.float32(0.0))
+
+    def init_cache(batch: int, max_seq: int):
+        one = ssm_lib.init_ssm_cache(cfg, batch, dtype)
+        hkv, hd = cfg.n_kv_heads, cfg.head_dim
+        return {
+            "ssm": jax.tree.map(
+                lambda a: jnp.zeros((cfg.n_layers,) + a.shape, a.dtype), one),
+            "attn_k": jnp.zeros((len(apps), batch, max_seq, hkv, hd), dtype),
+            "attn_v": jnp.zeros((len(apps), batch, max_seq, hkv, hd), dtype),
+        }
+
+    def decode_step(params, cache, tokens, pos):
+        from repro.serve.attention import decode_attention  # noqa: PLC0415
+        x = _embed(params, tokens, cfg, dtype)               # (B, d)
+        emb = x[:, None, :]
+        positions = jnp.asarray(pos)[None]
+
+        def ssm_body(x, xs, t=None):
+            lp, c = xs
+            h = rms_norm(x if t is None else x + t, lp["norm1"]["scale"],
+                         cfg.rms_eps)
+            y, c = ssm_lib.ssm_step(lp["ssm"], h, c, cfg, dtype)
+            return x + y, c
+
+        ssm_parts, ks, vs = [], [], []
+        for lo, hi, j in runs:
+            if hi > lo:
+                x, c = jax.lax.scan(ssm_body, x, (
+                    _tree_slice(params["layers"], lo, hi),
+                    _tree_slice(cache["ssm"], lo, hi)))
+                ssm_parts.append(c)
+            if j is None:
+                continue
+            kv = {}
+
+            def attend_cached(q, k, v, j=j, kv=kv):
+                a, kv["k"], kv["v"] = decode_attention(
+                    q, cache["attn_k"][j], cache["attn_v"][j], k, v, pos,
+                    mesh=mesh, batch_axes=batch_axes, scale=scale)
+                return a
+
+            t = shared_block(_tree_pick(params["shared"], j % n_blocks),
+                             _tree_pick(params["apps"], j), x[:, None, :],
+                             emb, positions, attend_cached)
+            x, c = ssm_body(x, (_tree_pick(params["layers"], hi),
+                                _tree_pick(cache["ssm"], hi)), t[:, 0, :])
+            ssm_parts.append(jax.tree.map(lambda a: a[None], c))
+            ks.append(kv["k"])
+            vs.append(kv["v"])
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
+        new_cache = {
+            "ssm": jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
+                                *ssm_parts),
+            "attn_k": jnp.stack(ks) if ks else cache["attn_k"],
+            "attn_v": jnp.stack(vs) if vs else cache["attn_v"],
+        }
+        return _logits(params, x, cfg, dtype), new_cache
 
     return Model(cfg, init, forward, init_cache, decode_step)
 

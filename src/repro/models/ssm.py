@@ -8,6 +8,11 @@ TP note (DESIGN.md §2): the fused in_proj of the reference CUDA implementation 
 split into separate per-stream projections (``wz/wx/wB/wC/wdt``) so each output
 dim shards cleanly on the ``model`` axis without cutting across stream
 boundaries — the TPU/GSPMD-native layout.
+
+The gated RMSNorm normalizes each group's ``d_inner / n_groups`` channels on
+their own, as the published block does (``RMSNormGated(group_size=d_inner //
+ngroups)``); one group is the whole width. With ``SSMConfig.conv_bias`` the
+depthwise conv carries a bias per channel (``conv_bias_x/B/C``).
 """
 
 from __future__ import annotations
@@ -51,11 +56,24 @@ def init_ssm(rng, cfg: ModelConfig):
         "conv_C": jnp.zeros((g * n, s.d_conv), jnp.float32),
         "scale": jnp.zeros((di,), jnp.float32),     # gated RMSNorm weight
         "out_proj": dense_init(r[7], (di, d)),
-    }
+    } | ({"conv_bias_x": jnp.zeros((di,), jnp.float32),
+          "conv_bias_B": jnp.zeros((g * n,), jnp.float32),
+          "conv_bias_C": jnp.zeros((g * n,), jnp.float32)}
+         if s.conv_bias else {})
 
 
-def _causal_conv(x, w, dtype, left=None):
-    """Depthwise causal conv1d. x: (B, L, C), w: (C, K).
+def gated_rms_norm(y, z, scale, groups: int, eps: float):
+    """RMSNorm of ``y * silu(z)`` over each of ``groups`` equal channel
+    groups. y, z: (..., C); scale: (C,) as a (1 + scale) weight."""
+    yz = y * jax.nn.silu(z)
+    if groups == 1:     # the whole width, with no size-1 group axis
+        return rms_norm(yz, scale, eps)
+    grouped = yz.reshape(yz.shape[:-1] + (groups, yz.shape[-1] // groups))
+    return rms_norm(grouped, scale.reshape(groups, -1), eps).reshape(yz.shape)
+
+
+def _causal_conv(x, w, dtype, left=None, bias=None):
+    """Depthwise causal conv1d. x: (B, L, C), w: (C, K), bias: (C,) or None.
 
     ``left`` (B, K-1, C) replaces the zero left-padding with real context —
     the context-parallel executor passes the previous cp rank's halo so the
@@ -70,6 +88,8 @@ def _causal_conv(x, w, dtype, left=None):
     out = jnp.zeros_like(x)
     for j in range(k):
         out = out + xp[:, j:j + x.shape[1], :] * w[None, None, :, j].astype(dtype)
+    if bias is not None:
+        out = out + bias.astype(dtype)
     return out
 
 
@@ -162,9 +182,12 @@ def ssm_block(p, x, cfg: ModelConfig, dtype, initial_state=None, plan=None):
     dt = jax.nn.softplus((x @ p["wdt"].astype(dtype)).astype(jnp.float32)
                          + p["dt_bias"])                      # (b, l, nh)
 
-    xin = jax.nn.silu(_causal_conv(xin, p["conv_x"], dtype))
-    Bv = jax.nn.silu(_causal_conv(Bv, p["conv_B"], dtype))
-    Cv = jax.nn.silu(_causal_conv(Cv, p["conv_C"], dtype))
+    xin = jax.nn.silu(_causal_conv(xin, p["conv_x"], dtype,
+                                   bias=p.get("conv_bias_x")))
+    Bv = jax.nn.silu(_causal_conv(Bv, p["conv_B"], dtype,
+                                  bias=p.get("conv_bias_B")))
+    Cv = jax.nn.silu(_causal_conv(Cv, p["conv_C"], dtype,
+                                  bias=p.get("conv_bias_C")))
 
     A = -jnp.exp(p["A_log"])                                  # (nh,)
     xh = xin.reshape(b, l, nh, s.head_dim)
@@ -174,7 +197,7 @@ def ssm_block(p, x, cfg: ModelConfig, dtype, initial_state=None, plan=None):
         initial_state=initial_state)
     y = y + xh.astype(jnp.float32) * p["D"][None, None, :, None]
     y = y.reshape(b, l, di).astype(dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["scale"], cfg.rms_eps)
+    y = gated_rms_norm(y, z, p["scale"], g, cfg.rms_eps)
     return y @ p["out_proj"].astype(dtype)
 
 
@@ -193,10 +216,12 @@ def init_ssm_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> Dict:
     }
 
 
-def _conv_step(cache_row, x_t, w, dtype):
+def _conv_step(cache_row, x_t, w, dtype, bias=None):
     """cache_row: (B, K-1, C); x_t: (B, C) -> (out (B, C), new cache)."""
     window = jnp.concatenate([cache_row, x_t[:, None, :]], axis=1)   # (B, K, C)
     out = jnp.einsum("bkc,ck->bc", window.astype(dtype), w.astype(dtype))
+    if bias is not None:
+        out = out + bias.astype(dtype)
     return out, window[:, 1:, :]
 
 
@@ -213,9 +238,12 @@ def ssm_step(p, x_t, cache, cfg: ModelConfig, dtype) -> Tuple[jax.Array, Dict]:
     dt = jax.nn.softplus((x_t @ p["wdt"].astype(dtype)).astype(jnp.float32)
                          + p["dt_bias"])                      # (B, nh)
 
-    xin, cx = _conv_step(cache["conv_x"], xin, p["conv_x"], dtype)
-    Bv, cb = _conv_step(cache["conv_B"], Bv, p["conv_B"], dtype)
-    Cv, cc = _conv_step(cache["conv_C"], Cv, p["conv_C"], dtype)
+    xin, cx = _conv_step(cache["conv_x"], xin, p["conv_x"], dtype,
+                         p.get("conv_bias_x"))
+    Bv, cb = _conv_step(cache["conv_B"], Bv, p["conv_B"], dtype,
+                        p.get("conv_bias_B"))
+    Cv, cc = _conv_step(cache["conv_C"], Cv, p["conv_C"], dtype,
+                        p.get("conv_bias_C"))
     xin, Bv, Cv = jax.nn.silu(xin), jax.nn.silu(Bv), jax.nn.silu(Cv)
 
     A = -jnp.exp(p["A_log"])
@@ -233,7 +261,7 @@ def ssm_step(p, x_t, cache, cfg: ModelConfig, dtype) -> Tuple[jax.Array, Dict]:
     y = jnp.einsum("bhpn,bhn->bhp", new_state, Ch)
     y = y + xh * p["D"][None, :, None]
     y = y.reshape(bsz, di).astype(dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["scale"], cfg.rms_eps)
+    y = gated_rms_norm(y, z, p["scale"], g, cfg.rms_eps)
     y = y @ p["out_proj"].astype(dtype)
     new_cache = {"conv_x": cx, "conv_B": cb, "conv_C": cc, "state": new_state}
     return y, new_cache

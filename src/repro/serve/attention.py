@@ -67,6 +67,7 @@ def decode_attention(
     softcap: float = 0.0,
     mesh: Optional[Mesh] = None,
     batch_axes: Tuple[str, ...] = ("data",),
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (attn_out (B, 1, Hq, hd), new_k_cache, new_v_cache).
 
@@ -77,7 +78,7 @@ def decode_attention(
     b, _, hq, hd = q.shape
     hkv = k_cache.shape[2]
     g = hq // hkv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     qg = q.reshape(b, hkv, g, hd)
 
     def _window_mask(valid, jpos):

@@ -715,9 +715,15 @@ def ssm_block_ex(ctx: ParallelContext, p, x, cfg: ModelConfig, dtype,
                                       xin.shape[-1] + Bv.shape[-1]], axis=-1)
     else:
         lx = lB = lC = None
-    xin = jax.nn.silu(ssm_lib._causal_conv(xin, conv_x, dtype, left=lx))
-    Bv = jax.nn.silu(ssm_lib._causal_conv(Bv, p["conv_B"], dtype, left=lB))
-    Cv = jax.nn.silu(ssm_lib._causal_conv(Cv, p["conv_C"], dtype, left=lC))
+    bias_x = p.get("conv_bias_x")
+    if bias_x is not None:
+        bias_x = _slice_tp(ctx, bias_x, di_l)
+    xin = jax.nn.silu(ssm_lib._causal_conv(xin, conv_x, dtype, left=lx,
+                                           bias=bias_x))
+    Bv = jax.nn.silu(ssm_lib._causal_conv(Bv, p["conv_B"], dtype, left=lB,
+                                          bias=p.get("conv_bias_B")))
+    Cv = jax.nn.silu(ssm_lib._causal_conv(Cv, p["conv_C"], dtype, left=lC,
+                                          bias=p.get("conv_bias_C")))
 
     A = -jnp.exp(_slice_tp(ctx, p["A_log"], nh_l))
     xh = xin.reshape(b, l, nh_l, s.head_dim)
@@ -765,7 +771,7 @@ def ssm_block_ex(ctx: ParallelContext, p, x, cfg: ModelConfig, dtype,
         yn = ((yz * jax.lax.rsqrt(ssq / di + cfg.rms_eps))
               * (1.0 + scale.astype(jnp.float32))).astype(dtype)
     else:
-        yn = rms_norm(y * jax.nn.silu(z), scale, cfg.rms_eps)
+        yn = ssm_lib.gated_rms_norm(y, z, scale, g, cfg.rms_eps)
     return _proj_rows(ctx, yn, p["out_proj"].astype(dtype))
 
 

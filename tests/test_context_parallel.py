@@ -309,6 +309,13 @@ def test_cp_matches_single_device_mamba2(multidevice):
     multidevice(_FAMILY_EQUIV_TEMPLATE.format(cfg=_SSM_CFG))
 
 
+def test_cp_matches_single_device_mamba2_groups_conv_bias(multidevice):
+    """Two groups (the gated norm per group) and the conv bias (Zamba2's
+    Mamba-2 layer) across cp shards."""
+    multidevice(_FAMILY_EQUIV_TEMPLATE.format(cfg=_SSM_CFG.replace(
+        "chunk=8)", "chunk=8, n_groups=2, conv_bias=True)")))
+
+
 def test_cp_tp_composition(multidevice):
     """CP × TP: cp ring attention inside tp-ring-gathered blocks (dense),
     loss/grads vs the single-device oracle on a (data, cp, model) mesh."""

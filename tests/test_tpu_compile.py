@@ -58,10 +58,10 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _attention(hq, hkv, hd, s, window=0, softcap=0.0):
+def _attention(hq, hkv, hd, s, window=0, softcap=0.0, scale=None):
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, window=window,
-                               softcap=softcap, interpret=False)
+                               softcap=softcap, scale=scale, interpret=False)
     shapes = [((1, hq, s, hd), jnp.bfloat16), ((1, hkv, s, hd), jnp.bfloat16),
               ((1, hkv, s, hd), jnp.bfloat16)]
     return fwd, shapes, (0, 1, 2), ("flash_fwd", "flash_dq", "flash_dkv")
@@ -88,13 +88,19 @@ def _gemm(e=8, c=512, d=2048, f=1024):
 # head dim 256, window 4096, softcap 50), mamba2-370m (32 heads, P=64, N=128,
 # chunk 128), zamba2-1.2b's Mamba-2 layers (64 heads, P=64, N=64, one group:
 # the head block's VMEM budget at twice the heads), olmoe-1b-7b experts
-# (d=2048, f=1024; 8 of its 64 experts)
+# (d=2048, f=1024; 8 of its 64 experts); zamba2-7b's chip share, one of two
+# tensor-parallel ranks: 16 attention heads of 224 (not a multiple of the
+# 128 lanes) with its (224/2)^-1/2 scale, and one Mamba-2 group of 56 heads
+# (N=64, chunk 256: head blocks of 28 forward, 14 backward)
 KERNELS = {
     "attention-qwen1.5-4b": lambda: _attention(20, 20, 128, 4096),
     "attention-gemma2-9b": lambda: _attention(16, 8, 256, 4096, window=4096,
                                               softcap=50.0),
     "ssd-mamba2-370m": _ssd,
     "ssd-zamba2-1.2b": lambda: _ssd(h=64, n=64),
+    "attention-zamba2-7b": lambda: _attention(16, 16, 224, 4096,
+                                              scale=112 ** -0.5),
+    "ssd-zamba2-7b": lambda: _ssd(h=56, n=64, chunk=256),
     "grouped-gemm-olmoe-1b-7b": _gemm,
 }
 
