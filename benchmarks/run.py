@@ -275,9 +275,8 @@ def bench_ssd():
     emit("ssd.xla_chunked.l512", us_x,
          f"decay_matrix_hbm_bytes={l_bytes};kernel_vmem_bytes={vmem}")
     us_k = timeit(lambda: ssd_chunk_scan(
-        x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
-        B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3), chunk=chunk)[0],
-        iters=1)
+        x, dt.transpose(0, 2, 1), A, B.transpose(0, 2, 1, 3),
+        C.transpose(0, 2, 1, 3), chunk=chunk)[0], iters=1)
     emit("ssd.pallas_interpret.l512", us_k,
          "note=python-interpreted;validates-correctness-not-speed")
 

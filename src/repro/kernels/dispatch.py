@@ -24,10 +24,12 @@ Every fused kernel here is differentiable (``jax.custom_vjp`` recompute
 backwards), so the dispatchers sit on the training path, not just prefill.
 
 Layout contracts: model code uses batch-major layouts ((B, S, H, hd) for
-attention, (B, L, H, P) for SSD); the kernels use head-major. The dispatchers
-own the transposes, plus the boundary padding for unaligned lengths (KV to the
-block boundary for blockwise attention, the sequence to the chunk boundary for
-SSD — never a silent fall-back to a quadratic whole-sequence path).
+attention, (B, L, H, P) for SSD). The attention kernels use head-major; the
+SSD kernels read x and write y in the model's layout and take only dt, B and
+C head- or group-major. The dispatchers own the transposes, plus the boundary
+padding for unaligned lengths (KV to the block boundary for blockwise
+attention, the sequence to the chunk boundary for SSD — never a silent
+fall-back to a quadratic whole-sequence path).
 
 Ring context parallelism (``train/executor.py``) gets two extra attention
 entries: :func:`dispatch_attention_lse` (per-chunk forward that also returns
@@ -50,7 +52,7 @@ from repro.models import layers as _layers
 from .flash_attention import (_pad_seq, flash_attention, flash_attention_bwd,
                               flash_attention_lse, resolve_interpret)
 from .grouped_gemm import expert_gemm
-from .ssd_scan import ssd_chunk_scan
+from .ssd_scan import has_lane_block, ssd_chunk_scan
 
 IMPLS = ("auto", "xla", "pallas")
 
@@ -271,11 +273,14 @@ def select_gemm_impl(impl: str) -> str:
                            auto_ok=True)
 
 
-def select_ssd_impl(impl: str, *, has_initial_state: bool = False) -> str:
+def select_ssd_impl(impl: str, *, has_initial_state: bool = False,
+                    lanes_ok: bool = True) -> str:
     """Resolve the SSD impl. The fused kernel starts from a zero state, so a
-    caller-supplied initial state takes the XLA scan under ``auto``."""
+    caller-supplied initial state takes the XLA scan under ``auto``; so do
+    heads with no lane-legal block (``ssd_scan.has_lane_block``)."""
     return _resolve_choice(impl, knob="ssm_impl",
-                           explicit_ok=not has_initial_state, auto_ok=True,
+                           explicit_ok=not has_initial_state,
+                           auto_ok=lanes_ok,
                            why="the kernel starts from a zero state")
 
 
@@ -528,15 +533,15 @@ def dispatch_ssd_scan(x, dt, A, B, C, *, chunk: int, impl: str = "auto",
         B = _pad_seq(B, 1, l_pad)
         C = _pad_seq(C, 1, l_pad)
 
-    choice = select_ssd_impl(impl, has_initial_state=initial_state is not None)
+    choice = select_ssd_impl(impl, has_initial_state=initial_state is not None,
+                             lanes_ok=has_lane_block(h, B.shape[2], p))
     if choice == "pallas":
+        # x and y stay as they are: the kernel reads the model's layout
         with jax.named_scope(LAYOUT_SCOPE):
-            x, dt = x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1)
+            dt = dt.transpose(0, 2, 1)
             B, C = B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3)
         y, state = ssd_chunk_scan(x, dt, A, B, C, chunk=chunk,
                                   interpret=resolve_interpret(interpret))
-        with jax.named_scope(LAYOUT_SCOPE):
-            y = y.transpose(0, 2, 1, 3)
     else:
         y, state = ssd_scan(x, dt, A, B, C, chunk=chunk,
                             initial_state=initial_state)

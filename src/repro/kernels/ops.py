@@ -50,8 +50,9 @@ def expert_gemm(x, w, group_sizes=None, *, block_c=128, block_f=128,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_scan(x, dt, A, Bm, Cm, *, chunk=128, interpret=None):
-    """Fused Mamba2 SSD: (B,H,L,P) inputs -> (y, final_state); the intra-chunk
-    decay matrices and the running state stay in VMEM.
+    """Fused Mamba2 SSD: x (B,L,H,P), dt (B,H,L), B/C (B,G,L,N) -> (y
+    (B,L,H,P), final_state); the intra-chunk decay matrices and the running
+    state stay in VMEM.
 
     Differentiable: the forward saves only per-chunk entering states and the
     backward kernel recomputes the decay/score tiles (see ssd_scan.py).

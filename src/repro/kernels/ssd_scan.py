@@ -11,46 +11,63 @@ either pass.
 
 Layout (TPU adaptation — same pattern as flash_attention.py):
 
-- grid = (batch, groups · head_blocks, n_chunks) with the chunk dim minor.
-  One grid step holds ``hb`` heads of one group for one chunk: x and y as
-  (hb, q, p) blocks, dt as one (hb, q) tile (rows of heads), A as (hb, 1),
-  and that group's B and C as one (q, n) block each, fetched once for all
-  ``hb`` heads. Heads are group-major, so head block j belongs to group
-  j // head_blocks.
-- the (hb, p, n) running states live in VMEM scratch across chunk steps (the
+- x and y (and in the backward dy and dx) stay in the model's layout,
+  viewed as (B, L, H·P), a free reshape of (B, L, H, P): nothing is
+  transposed into or out of the kernels. grid = (batch, groups ·
+  head_blocks, n_chunks) with the chunk dim minor. One grid step holds
+  ``hb`` heads of one group for one chunk: x and y as one (q, hb·p) block
+  whose lanes [i·p, (i+1)·p) are head i (heads are group-major, so head
+  block j is a contiguous lane range and belongs to group j //
+  head_blocks); dt as one (hb, q) tile (rows of heads); A as (hb, 1); and
+  that group's B and C as one (q, n) block each, fetched once for all
+  ``hb`` heads. The caller lays out only these: dt travels as (B, H/hb, hb,
+  L), since a lane-legal (q, hb) block of (B, L, H) would need hb = H or a
+  multiple of 128 (2 MB fp32 a layer in mamba2-370m); B and C as (B, G, L,
+  N), a bitcast at one group.
+- the block's heads go a lane tile at a time: as many P-wide heads side by
+  side as fill 128 lanes (two at P 64, one at P 128; :func:`_tiles`). Per
+  head, ``scores @ xd`` runs against the whole tile and a lane mask keeps
+  the head's own lanes, at the MXU cost of a (q, p) product; the
+  carried-state term ``C·stateᵀ`` and the state update ``(xd ∘ decay)ᵀ·B``
+  run once per tile on its heads' stacked (k·p, n) states.
+- the (hb·p, n) running states live in VMEM scratch across chunk steps (the
   recurrence the GPU implementation does with a separate kernel launch +
   global memory round trip).
 - per grid step, once: ``C·Bᵀ`` (q, q); the block's cumulative log-decays
   ``cs = cumsum(dt·A)`` as rows (hb, q), one triangular product at fp32
   precision, and their transposes (q, hb), so each head's column terms are a
-  lane slice. Then an
-  unrolled loop over the block's heads forms each head's decay matrix L,
-  ``scores = CB ∘ L``, its y, carried-state term and state update.
+  lane slice. Then an unrolled loop over the block's heads forms each
+  head's decay matrix L, ``scores = CB ∘ L``, its y, carried-state term and
+  state update.
 - ``hb`` is the largest divisor of the heads per group (as the kernel sees
-  them: the local count under tensor parallelism) whose VMEM working set —
-  every block double-buffered, with (8, 128) tile padding, plus scratch and
-  the (q, q) temporaries, all counted at fp32 — fits ``VMEM_BUDGET`` (24 MiB)
-  under the 32-MiB scoped limit both kernels ask for (:func:`ssd_head_block`,
-  :func:`vmem_bytes`). mamba2-370m (32 heads, P 64, N 128, q 128): one block
-  of 32 heads in both passes, 128 grid steps a call at 8 x 2048 tokens;
-  zamba2-1.2b (64 heads, N 64): blocks of 32; zamba2-7b's tensor-parallel
-  share (56 heads, N 64, chunk 256): 28 forward, 14 backward.
+  them: the local count under tensor parallelism) that makes a lane-legal
+  block (hb·p a multiple of 128, or every head: hb = H) and whose VMEM
+  working set — every block double-buffered, the (q, hb·p) activation
+  blocks unpadded and the rest with (8, 128) tile padding, plus scratch and
+  the (q, q) temporaries, all counted at fp32 — fits ``VMEM_BUDGET`` (24
+  MiB) under the 32-MiB scoped limit both kernels ask for
+  (:func:`ssd_head_block`, :func:`vmem_bytes`). mamba2-370m (32 heads, P
+  64, N 128, q 128): one block of 32 heads in both passes, 128 grid steps a
+  call at 8 x 2048 tokens; zamba2-1.2b (64 heads, N 64): one block of 64;
+  zamba2-7b's tensor-parallel share (56 heads, N 64, chunk 256): 28 in
+  both passes.
 
 Backward follows the FlashAttention-2 recipe (PAPERS.md): the forward
-additionally saves only the state *entering* each chunk — an (nc, p, n) strip
-per (batch, head), the logsumexp analogue — and a reversed-grid backward
-kernel recomputes the decay matrix ``L`` and the intra-chunk scores tile by
-tile in VMEM to produce ``dx/ddt/dA/dB/dC``:
+additionally saves only the state *entering* each chunk — (B, nc, H·P, N),
+an (nc, p, n) strip per (batch, head), the logsumexp analogue — and a
+reversed-grid backward kernel recomputes the decay matrix ``L`` and the
+intra-chunk scores tile by tile in VMEM to produce ``dx/ddt/dA/dB/dC``:
 
 - the same grid and head blocks, sweeping chunks *last to first* (the index
-  maps flip the chunk coordinate); the (hb, p, n) state cotangents ``dS``
+  maps flip the chunk coordinate); the (hb·p, n) state cotangents ``dS``
   ride across steps in VMEM scratch, seeded by the final-state cotangent,
   propagated by ``dS_in = exp(cs[-1])·dS_out + (dy ∘ exp(cs))ᵀ·C``.
 - per-chunk, all (q, q) quantities (L, scores, dscores) are recomputed from
   the streamed-in x/dt/B/C, never written to HBM. ``C·Bᵀ`` is formed once
   per grid step; ``Σ_h dcb_h`` accumulates in a (q, q) scratch, and
   ``(Σ dcb)·B`` and ``(Σ dcb)ᵀ·C`` run once per grid step. The per-head
-  terms of dB and dC accumulate in the (q, n) output blocks themselves.
+  terms of dB, and dC's per lane tile, accumulate in the (q, n) output
+  blocks themselves.
 - dB and dC leave the kernel as (b, groups · head_blocks, l, n): summed over
   each block's heads inside the kernel, and over a group's head blocks
   outside (no sum at all when one block holds the group).
@@ -59,7 +76,8 @@ tile in VMEM to produce ``dx/ddt/dA/dB/dC``:
   gathered into rows, and one suffix-sum product over all the block's heads
   turns them into ``dda``; outside, ``dA_h = Σ dda·dt``.
 
-Every dot takes the same fp32 operands as the per-head formulation and
+Every output element comes from the same dot products, over the same fp32
+operands, as the per-head formulation (a lane mask adds exact zeros), and
 accumulates in fp32, at Mosaic's default precision; the cumulative sums,
 vector reductions before, are HIGHEST-precision products. Only sums over
 heads, and the order of the cumulative sums, are reassociated.
@@ -74,6 +92,7 @@ everywhere else.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -102,8 +121,9 @@ def _tile_bytes(rows: int, cols: int) -> int:
 def vmem_bytes(hb: int, p: int, n: int, chunk: int, backward: bool) -> int:
     """VMEM working set of one grid step over ``hb`` heads, every stream
     counted at fp32: the blocks double-buffered, plus scratch and the (q, q)
-    temporaries of one head."""
-    head, rows, rates = (_tile_bytes(chunk, p), _tile_bytes(hb, chunk),
+    temporaries of one head. A head's share of the (q, hb·p) activation
+    blocks is unpadded: its p lanes sit beside its neighbours'."""
+    head, rows, rates = (chunk * p * _F32, _tile_bytes(hb, chunk),
                          _tile_bytes(hb, 1))
     state, qn, qq = (_tile_bytes(p, n), _tile_bytes(chunk, n),
                      _tile_bytes(chunk, chunk))
@@ -123,16 +143,34 @@ def vmem_bytes(hb: int, p: int, n: int, chunk: int, backward: bool) -> int:
     return 2 * blocks + scratch + temps
 
 
+def _lane_legal(hb: int, p: int, heads: int) -> bool:
+    """A (q, hb·p) block of the (B, L, H·P) view is a legal TPU block: a
+    multiple of the 128 lanes, or all of them."""
+    return (hb * p) % 128 == 0 or hb == heads
+
+
+def has_lane_block(heads: int, groups: int, p: int) -> bool:
+    """Some head block of one group is lane-legal (:func:`ssd_head_block`).
+    If a whole group is not, no divisor of it is."""
+    return _lane_legal(heads // groups, p, heads)
+
+
 def ssd_head_block(heads_per_group: int, p: int, n: int, chunk: int,
-                   backward: bool = False) -> int:
-    """Heads one grid step holds: the largest divisor of ``heads_per_group``
-    whose working set (:func:`vmem_bytes`) fits ``VMEM_BUDGET``; 1 if none
-    does."""
-    for hb in range(heads_per_group, 0, -1):
-        if (heads_per_group % hb == 0
-                and vmem_bytes(hb, p, n, chunk, backward) <= VMEM_BUDGET):
+                   backward: bool = False, groups: int = 1) -> int:
+    """Heads one grid step holds: the largest lane-legal divisor of
+    ``heads_per_group`` whose working set (:func:`vmem_bytes`) fits
+    ``VMEM_BUDGET``; the smallest lane-legal one if none does."""
+    legal = [hb for hb in range(heads_per_group, 0, -1)
+             if heads_per_group % hb == 0
+             and _lane_legal(hb, p, heads_per_group * groups)]
+    if not legal:
+        raise ValueError(
+            f"no head block of {heads_per_group} heads of P {p} in "
+            f"{groups} groups spans a multiple of 128 lanes")
+    for hb in legal:
+        if vmem_bytes(hb, p, n, chunk, backward) <= VMEM_BUDGET:
             return hb
-    return 1
+    return legal[-1]
 
 
 def _lower_tri(q: int, upper: bool = False):
@@ -196,12 +234,55 @@ class _Decay:
         return jnp.exp(jnp.where(self.tri, li, -jnp.inf))
 
 
+class _Tile:
+    """Heads side by side in one lane tile of a block: lanes [start, start +
+    k·p) of the (q, hb·p) activation blocks, and the same rows of the (hb·p,
+    n) blocks of states. Head ``heads[j]`` owns the tile's j-th p-wide
+    range."""
+
+    def __init__(self, heads: range, p: int):
+        self.heads, self.p = heads, p
+        self.span = slice(heads[0] * p, (heads[-1] + 1) * p)
+        width = len(heads) * p
+        self.lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        self.row = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+
+    def _spread(self, pick, pos):
+        # head heads[j]'s value over positions [j·p, (j+1)·p) of ``pos``
+        out = pick(self.heads[-1])
+        for j in range(len(self.heads) - 2, -1, -1):
+            out = jnp.where(pos < (j + 1) * self.p, pick(self.heads[j]), out)
+        return out
+
+    def lanes(self, cols):
+        """(q, hb) columns, one a head -> each head's over its lanes."""
+        return self._spread(lambda i: cols[:, i:i + 1], self.lane)
+
+    def rows(self, vals):
+        """(hb, 1) values, one a head -> each head's over its state rows."""
+        return self._spread(lambda i: vals[i:i + 1, :], self.row)
+
+    def select(self, j, v, other):
+        """Head ``heads[j]``'s lanes from v, the others' from ``other``."""
+        if len(self.heads) == 1:
+            return v
+        own = (self.lane >= j * self.p) & (self.lane < (j + 1) * self.p)
+        return jnp.where(own, v, other)
+
+
+def _tiles(hb: int, p: int):
+    """A block's heads by lane tile: as many P-wide heads as fill 128 lanes
+    (two at P 64), one where P does not divide 128."""
+    k = math.gcd(hb, 128 // p) if 128 % p == 0 else 1
+    return [_Tile(range(t, t + k), p) for t in range(0, hb, k)]
+
+
 # ---------------------------------------------------------------------------
 # forward
 
 
 def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *refs,
-                n_chunks: int, hb: int):
+                n_chunks: int, hb: int, p: int):
     # refs = (enter_ref?, state_out_ref, state_ref): the entering-states
     # residual output only exists when the VJP will need it — forward-only
     # calls (eval/decode) skip that extra HBM write entirely
@@ -218,21 +299,25 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *refs,
     cb = _dot_nt(cmat, bmat)                      # (q, q), once per group
     d = _Decay(dt_ref[0, 0].astype(jnp.float32), a_ref[0])
 
-    for i in range(hb):
-        h = slice(i, i + 1)
-        xd = x_ref[0, i].astype(jnp.float32) * d.dt_t[:, h]   # (q, p)
-        y = _dot(cb * d.L(i), xd)
+    for tile in _tiles(hb, p):
+        xd = x_ref[0, :, tile.span].astype(jnp.float32) * tile.lanes(d.dt_t)
+        y = None
+        for j, i in enumerate(tile.heads):
+            # head i's lanes of (CB ∘ L_i) @ xd are its y
+            yi = _dot(cb * d.L(i), xd)
+            y = yi if y is None else tile.select(j, yi, y)
 
-        # carried-state contribution: y += exp(cs) * C @ state  (state: (p, n))
-        state = state_ref[i]
+        # carried-state contribution: y += exp(cs) * C @ stateᵀ, the tile's
+        # (k·p, n) states at once
+        state = state_ref[tile.span]
         if enter_ref is not None:
-            enter_ref[0, i, 0] = state.astype(enter_ref.dtype)  # bwd residual
-        y = y + d.exp_cs_t[:, h] * _dot_nt(cmat, state)
+            enter_ref[0, 0, tile.span] = state.astype(enter_ref.dtype)
+        y = y + tile.lanes(d.exp_cs_t) * _dot_nt(cmat, state)
 
         # state recurrence: state' = exp(cs[-1])·state + Σ_q exp(cs[-1]-cs)·xdᵀB
-        state_ref[i] = (state * d.exp_end[h, :]
-                        + _dot_tn(xd * d.decay_t[:, h], bmat))
-        y_ref[0, i] = y.astype(y_ref.dtype)
+        state_ref[tile.span] = (state * tile.rows(d.exp_end)
+                                + _dot_tn(xd * tile.lanes(d.decay_t), bmat))
+        y_ref[0, :, tile.span] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -241,11 +326,11 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *refs,
 
 def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool,
                  hb: int):
-    """Returns (y (B,H,L,P) fp32, entering states (B,H,nc,P,N) fp32 or None,
+    """Returns (y (B,L,H,P) fp32, entering states (B,nc,H·P,N) fp32 or None,
     final_state (B,H,P,N) fp32). ``save_enters`` is True only under the VJP —
     forward-only calls skip the residual's HBM write. ``hb`` heads of one
     group share each grid step."""
-    b, h, l, p = x.shape
+    b, l, h, p = x.shape
     g, n = Bm.shape[1], Bm.shape[3]
     assert l % chunk == 0, (l, chunk)
     assert h % g == 0
@@ -255,18 +340,20 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool,
     nc = l // chunk
     grid = (b, g * nhb, nc)
 
+    # x and y as (B, L, H·P): head block j is lanes [j·hb·p, (j+1)·hb·p)
+    act_spec = pl.BlockSpec((1, chunk, hb * p), lambda bi, j, ci: (bi, ci, j))
     out_specs = [
-        pl.BlockSpec((1, hb, chunk, p), lambda bi, j, ci: (bi, j, ci, 0)),
-        pl.BlockSpec((1, hb, p, n), lambda bi, j, ci: (bi, j, 0, 0)),
+        act_spec,
+        pl.BlockSpec((1, hb * p, n), lambda bi, j, ci: (bi, j, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, h, l, p), jnp.float32),
-        jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
+        jax.ShapeDtypeStruct((b, l, h * p), jnp.float32),
+        jax.ShapeDtypeStruct((b, h * p, n), jnp.float32),
     ]
     if save_enters:
         out_specs.insert(1, pl.BlockSpec(
-            (1, hb, 1, p, n), lambda bi, j, ci: (bi, j, ci, 0, 0)))
-        out_shape.insert(1, jax.ShapeDtypeStruct((b, h, nc, p, n),
+            (1, 1, hb * p, n), lambda bi, j, ci: (bi, ci, j, 0)))
+        out_shape.insert(1, jax.ShapeDtypeStruct((b, nc, h * p, n),
                                                  jnp.float32))
     group_spec = pl.BlockSpec((1, 1, chunk, n),
                               lambda bi, j, ci: (bi, j // nhb, ci, 0))
@@ -274,10 +361,10 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool,
     # block's per-step rows, and its decay rates, each as one 2-D tile
 
     outs = pl.pallas_call(
-        functools.partial(_fwd_kernel, n_chunks=nc, hb=hb),
+        functools.partial(_fwd_kernel, n_chunks=nc, hb=hb, p=p),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hb, chunk, p), lambda bi, j, ci: (bi, j, ci, 0)),
+            act_spec,
             pl.BlockSpec((1, 1, hb, chunk), lambda bi, j, ci: (bi, j, 0, ci)),
             pl.BlockSpec((1, hb, 1), lambda bi, j, ci: (j, 0, 0)),
             group_spec,
@@ -285,16 +372,15 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((hb, p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb * p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="ssd_fwd",
-    )(x, dt.reshape(b, h // hb, hb, l),
+    )(x.reshape(b, l, h * p), dt.reshape(b, h // hb, hb, l),
       A.astype(jnp.float32).reshape(-1, hb, 1), Bm, Cm)
-    if save_enters:
-        return outs[0], outs[1], outs[2]
-    return outs[0], None, outs[1]
+    y, state = outs[0].reshape(b, l, h, p), outs[-1].reshape(b, h, p, n)
+    return y, (outs[1] if save_enters else None), state
 
 
 # ---------------------------------------------------------------------------
@@ -303,7 +389,7 @@ def _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool,
 
 def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
                 dsf_ref, dx_ref, ddt_ref, dda_ref, db_ref, dc_ref,
-                dstate_ref, dcb_ref, dcs_ref, *, hb: int):
+                dstate_ref, dcb_ref, dcs_ref, *, hb: int, p: int):
     ci = pl.program_id(2)   # reversed sweep: index maps flip to chunk nc-1-ci
 
     @pl.when(ci == 0)
@@ -328,56 +414,72 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
     db_ref[0, 0] = jnp.zeros(db_ref.shape[2:], db_ref.dtype)
     dc_ref[0, 0] = jnp.zeros(dc_ref.shape[2:], dc_ref.dtype)
 
-    for i in range(hb):
-        h = slice(i, i + 1)
-        x = x_ref[0, i].astype(jnp.float32)      # (q, p)
-        sin = enter_ref[0, i, 0].astype(jnp.float32)  # (p, n) entering state
-        dy = dy_ref[0, i].astype(jnp.float32)    # (q, p)
-        ds_out = dstate_ref[i]                    # (p, n) cotangent of S_out
-        dt_col, decay = d.dt_t[:, h], d.decay_t[:, h]       # (q, 1)
-        xd = x * dt_col
-        L = d.L(i)
-        scores = cb * L
+    for tile in _tiles(hb, p):
+        x = x_ref[0, :, tile.span].astype(jnp.float32)      # (q, k·p)
+        dy = dy_ref[0, :, tile.span].astype(jnp.float32)    # (q, k·p)
+        sin = enter_ref[0, 0, tile.span].astype(jnp.float32)  # (k·p, n)
+        ds_out = dstate_ref[tile.span]            # (k·p, n) cotangent of S_out
+        dt_w, decay_w = tile.lanes(d.dt_t), tile.lanes(d.decay_t)
+        exp_cs_w = tile.lanes(d.exp_cs_t)
+        xd = x * dt_w
 
-        # --- intra-chunk "attention" term: y_diag = scores @ xd
-        dscores = _dot_nt(dy, xd)                 # (q, q)
-        dxd = _dot_tn(scores, dy)                 # (q, p)
-        dcb_ref[...] += dscores * L
-
-        # --- carried-state term: y_off = exp(cs) ∘ (C @ sinᵀ)
-        y_off = d.exp_cs_t[:, h] * _dot_nt(cmat, sin)               # (q, p)
-        dy_e = dy * d.exp_cs_t[:, h]
+        # --- carried-state term: y_off = exp(cs) ∘ (C @ sinᵀ), the tile's
+        # heads at once
+        y_off = exp_cs_w * _dot_nt(cmat, sin)                     # (q, k·p)
+        dy_e = dy * exp_cs_w
         dc_ref[0, 0] += _dot(dy_e, sin)
+        dy_off = dy * y_off
 
         # --- state-recurrence term: S_out = exp(cs[-1])·sin + Σ ds_i·xd_i⊗B_i
-        xd_ds = _dot(xd, ds_out)                  # (q, n)
-        dxd = dxd + decay * _dot_nt(bmat, ds_out)
-        db_ref[0, 0] += decay * xd_ds
+        dxd_state = decay_w * _dot_nt(bmat, ds_out)               # (q, k·p)
+        ds_sin = ds_out * sin
 
-        # --- cotangent of the cumulative log-decays cs. The terms summed
-        # over lanes come out as a column: G's rows, exp(cs) in y_off,
-        # exp(cs[-1]-cs) in the state update. G's column sums come out as a
-        # row.
-        G = dscores * scores                       # dL ∘ L, zero above diagonal
-        t_qn = decay * xd_ds * bmat
-        dcs_col = (G.sum(axis=1, keepdims=True)
-                   + (dy * y_off).sum(axis=1, keepdims=True)
-                   - t_qn.sum(axis=1, keepdims=True))           # (q, 1)
-        # cs = cumsum(da)  =>  dda_s = Σ_{t>=s} dcs_t. The column part's suffix
-        # sums are taken here as a row; the row part is summed for all heads
-        # after the loop. The two cs[-1] contributions (Σt from decay, the
-        # exp(cs[-1])·sin term) land on every entry, so they join the total.
-        last = (t_qn.sum(keepdims=True)
-                + d.exp_end[h, :] * (ds_out * sin).sum(keepdims=True))
-        dcs_ref[0, h, :] = (jnp.where(suffix, dcs_col, 0.0)
-                            .sum(axis=0, keepdims=True) + last)
-        dcs_ref[1, h, :] = -G.sum(axis=0, keepdims=True)
-        dcs_ref[2, h, :] = jnp.where(eye, (dxd * x).sum(axis=1, keepdims=True),
-                                     0.0).sum(axis=0, keepdims=True)
+        dxd = dxd_state
+        for j, i in enumerate(tile.heads):
+            h = slice(i, i + 1)
+            decay = d.decay_t[:, h]                               # (q, 1)
+            L = d.L(i)
+            scores = cb * L
 
-        # propagate the state cotangent to the previous chunk
-        dstate_ref[i] = d.exp_end[h, :] * ds_out + _dot_tn(dy_e, cmat)
-        dx_ref[0, i] = (dxd * dt_col).astype(dx_ref.dtype)
+            # --- intra-chunk "attention" term: y_diag = scores @ xd; a lane
+            # mask on dy keeps head i's products, and head i's lanes of
+            # scoresᵀ·dy are its dxd
+            dscores = _dot_nt(tile.select(j, dy, 0.0), xd)        # (q, q)
+            dxd_i = _dot_tn(scores, dy) + dxd_state
+            dcb_ref[...] += dscores * L
+
+            xd_ds = _dot(tile.select(j, xd, 0.0), ds_out)        # (q, n)
+            db_ref[0, 0] += decay * xd_ds
+
+            # --- cotangent of the cumulative log-decays cs. The terms summed
+            # over lanes come out as a column: G's rows, exp(cs) in y_off,
+            # exp(cs[-1]-cs) in the state update. G's column sums come out as
+            # a row.
+            G = dscores * scores                   # dL ∘ L, zero above diagonal
+            t_qn = decay * xd_ds * bmat
+            dcs_col = (G.sum(axis=1, keepdims=True)
+                       + tile.select(j, dy_off, 0.0).sum(axis=1, keepdims=True)
+                       - t_qn.sum(axis=1, keepdims=True))       # (q, 1)
+            # cs = cumsum(da)  =>  dda_s = Σ_{t>=s} dcs_t. The column part's
+            # suffix sums are taken here as a row; the row part is summed for
+            # all heads after the loop. The two cs[-1] contributions (Σt from
+            # decay, the exp(cs[-1])·sin term) land on every entry, so they
+            # join the total.
+            last = (t_qn.sum(keepdims=True)
+                    + d.exp_end[h, :]
+                    * ds_sin[j * p:(j + 1) * p].sum(keepdims=True))
+            dcs_ref[0, h, :] = (jnp.where(suffix, dcs_col, 0.0)
+                                .sum(axis=0, keepdims=True) + last)
+            dcs_ref[1, h, :] = -G.sum(axis=0, keepdims=True)
+            dxd_x = tile.select(j, dxd_i * x, 0.0).sum(axis=1, keepdims=True)
+            dcs_ref[2, h, :] = jnp.where(eye, dxd_x, 0.0).sum(axis=0,
+                                                              keepdims=True)
+            dxd = tile.select(j, dxd_i, dxd)
+
+        # propagate the state cotangents to the previous chunk
+        dstate_ref[tile.span] = (tile.rows(d.exp_end) * ds_out
+                                 + _dot_tn(dy_e, cmat))
+        dx_ref[0, :, tile.span] = (dxd * dt_w).astype(dx_ref.dtype)
 
     # the row part's suffix sums, for all heads as one triangular product
     dda = dcs_ref[0] + _row_scan(dcs_ref[1], reverse=True)
@@ -394,17 +496,16 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, enter_ref, dy_ref,
 def _ssd_backward(chunk, interpret, res, g):
     x, dt, A, Bm, Cm, enters = res
     dy, dsf = g
-    b, h, l, p = x.shape
+    b, l, h, p = x.shape
     grp, n = Bm.shape[1], Bm.shape[3]
-    hpg = h // grp
-    hb = ssd_head_block(hpg, p, n, chunk, backward=True)
+    hb = ssd_head_block(h // grp, p, n, chunk, backward=True, groups=grp)
     return _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk,
                                 interpret, hb)
 
 
 def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
                          hb: int):
-    b, h, l, p = x.shape
+    b, l, h, p = x.shape
     grp, n = Bm.shape[1], Bm.shape[3]
     hpg = h // grp
     assert hpg % hb == 0, (hpg, hb)
@@ -413,12 +514,11 @@ def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
     grid = (b, grp * nhb, nc)
     rev = nc - 1   # index maps sweep chunks last -> first
 
-    def heads(*block):
-        # one block of the block's heads at the chunk, per-head arrays
-        zeros = (0,) * (len(block) - 1)
-        return pl.BlockSpec((1, hb) + block,
-                            lambda bi, j, ci: (bi, j, rev - ci) + zeros)
-
+    # x, dy and dx as (B, L, H·P), the entering states as (B, nc, H·P, N)
+    act_spec = pl.BlockSpec((1, chunk, hb * p),
+                            lambda bi, j, ci: (bi, rev - ci, j))
+    enter_spec = pl.BlockSpec((1, 1, hb * p, n),
+                              lambda bi, j, ci: (bi, rev - ci, j, 0))
     rows_spec = pl.BlockSpec((1, 1, hb, chunk),
                              lambda bi, j, ci: (bi, j, 0, rev - ci))
     group_spec = pl.BlockSpec((1, 1, chunk, n),
@@ -429,22 +529,21 @@ def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
     rows_shape = jax.ShapeDtypeStruct((b, grp * nhb, hb, l), jnp.float32)
 
     dx, ddt, dda, db, dc = pl.pallas_call(
-        functools.partial(_bwd_kernel, hb=hb),
+        functools.partial(_bwd_kernel, hb=hb, p=p),
         grid=grid,
         in_specs=[
-            heads(chunk, p),
+            act_spec,
             rows_spec,
             pl.BlockSpec((1, hb, 1), lambda bi, j, ci: (j, 0, 0)),
             group_spec,
             group_spec,
-            heads(1, p, n),
-            heads(chunk, p),
-            pl.BlockSpec((1, hb, p, n), lambda bi, j, ci: (bi, j, 0, 0)),
+            enter_spec,
+            act_spec,
+            pl.BlockSpec((1, hb * p, n), lambda bi, j, ci: (bi, j, 0)),
         ],
-        out_specs=[heads(chunk, p), rows_spec, rows_spec, block_spec,
-                   block_spec],
+        out_specs=[act_spec, rows_spec, rows_spec, block_spec, block_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, l, p), jnp.float32),
+            jax.ShapeDtypeStruct((b, l, h * p), jnp.float32),
             rows_shape,
             rows_shape,
             jax.ShapeDtypeStruct((b, grp * nhb, l, n), jnp.float32),
@@ -452,16 +551,17 @@ def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
         ],
         # dS per head; Σ dcb; rows per head of the cs cotangent: the column
         # part's suffix sums, the row part, and Σ_p dxd ∘ x for ddt
-        scratch_shapes=[pltpu.VMEM((hb, p, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((hb * p, n), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32),
                         pltpu.VMEM((3, hb, chunk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="ssd_bwd",
-    )(x, dt.reshape(b, h // hb, hb, l),
+    )(x.reshape(b, l, h * p), dt.reshape(b, h // hb, hb, l),
       A.astype(jnp.float32).reshape(-1, hb, 1), Bm, Cm, enters,
-      dy.astype(jnp.float32), dsf.astype(jnp.float32))
+      dy.astype(jnp.float32).reshape(b, l, h * p),
+      dsf.astype(jnp.float32).reshape(b, h * p, n))
     ddt, dda = ddt.reshape(b, h, l), dda.reshape(b, h, l)
 
     # block sums of B/C gradients -> sum a group's head blocks onto the shared
@@ -470,7 +570,8 @@ def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
     dC = dc.reshape(b, grp, nhb, l, n).sum(axis=2).astype(Cm.dtype)
     # da = dt·A  =>  dA_h = Σ_{b,l} dda·dt (cheap elementwise reduction in XLA)
     dA = jnp.einsum("bhl,bhl->h", dda, dt.astype(jnp.float32)).astype(A.dtype)
-    return dx.astype(x.dtype), ddt.astype(dt.dtype), dA, dB, dC
+    return (dx.reshape(b, l, h, p).astype(x.dtype), ddt.astype(dt.dtype), dA,
+            dB, dC)
 
 
 # ---------------------------------------------------------------------------
@@ -478,8 +579,9 @@ def _ssd_backward_blocks(x, dt, A, Bm, Cm, enters, dy, dsf, chunk, interpret,
 
 
 def _forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters: bool):
-    hpg = x.shape[1] // Bm.shape[1]
-    hb = ssd_head_block(hpg, x.shape[3], Bm.shape[3], chunk)
+    grp = Bm.shape[1]
+    hb = ssd_head_block(x.shape[2] // grp, x.shape[3], Bm.shape[3], chunk,
+                        groups=grp)
     return _ssd_forward(x, dt, A, Bm, Cm, chunk, interpret, save_enters, hb)
 
 
@@ -505,7 +607,7 @@ _ssd.defvjp(_ssd_fwd, _ssd_backward)
 
 
 def ssd_chunk_scan(
-    x: jax.Array,        # (B, H, L, P)
+    x: jax.Array,        # (B, L, H, P), the model's layout
     dt: jax.Array,       # (B, H, L)
     A: jax.Array,        # (H,) negative decay rates
     Bm: jax.Array,       # (B, G, L, N)
@@ -514,6 +616,6 @@ def ssd_chunk_scan(
     chunk: int = 128,
     interpret: Optional[bool] = None,   # None -> compiled on TPU, interpreted elsewhere
 ):
-    """Fused differentiable SSD. Returns (y (B, H, L, P) fp32,
+    """Fused differentiable SSD. Returns (y (B, L, H, P) fp32,
     final_state (B, H, P, N) fp32)."""
     return _ssd(x, dt, A, Bm, Cm, int(chunk), resolve_interpret(interpret))

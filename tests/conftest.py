@@ -40,12 +40,14 @@ def multidevice():
 @pytest.fixture
 def force_head_block(monkeypatch):
     """Shrink the SSD kernels' VMEM budget until a pass takes ``hb`` heads a
-    grid step: ``force(hb, heads_per_group, p, n, chunk, backward=False)``."""
+    grid step: ``force(hb, heads_per_group, p, n, chunk, backward=False,
+    groups=1)``."""
     from repro.kernels import ssd_scan as S
 
-    def force(hb, heads_per_group, p, n, chunk, backward=False):
+    def force(hb, heads_per_group, p, n, chunk, backward=False, groups=1):
         monkeypatch.setattr(S, "VMEM_BUDGET",
                             S.vmem_bytes(hb, p, n, chunk, backward))
-        assert S.ssd_head_block(heads_per_group, p, n, chunk, backward) == hb
+        assert S.ssd_head_block(heads_per_group, p, n, chunk, backward,
+                                groups) == hb
 
     return force

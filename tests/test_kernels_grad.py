@@ -88,14 +88,15 @@ def test_expert_gemm_group_sizes_zero_expert():
 
 
 SSD_GRAD_CASES = [
-    # (b, l, h, p, g, n, chunk[, backward head block forced by a small budget])
+    # (b, l, h, p, g, n, chunk[, backward head block forced by a small
+    # budget]); a head block spans a multiple of 128 lanes, or all H·P
     (1, 32, 2, 4, 1, 4, 8),
-    (2, 48, 4, 8, 2, 8, 16),       # GQA-style g < h
-    (1, 24, 4, 4, 2, 4, 24),       # single chunk, g < h
+    (2, 48, 4, 64, 2, 8, 16),      # GQA-style g < h
+    (1, 24, 4, 64, 2, 4, 24),      # single chunk, g < h
     (1, 32, 8, 4, 1, 8, 8),        # one group, one block of all 8 heads
-    (2, 48, 8, 8, 2, 8, 16, 2),    # two head blocks per group, g = 2
-    (1, 16, 6, 4, 3, 4, 16, 1),    # single chunk, g = 3, one head a block
-    (1, 64, 6, 8, 1, 16, 16, 3),   # blocks of 3 of 6 heads
+    (2, 48, 8, 64, 2, 8, 16, 2),   # two head blocks per group, g = 2
+    (1, 16, 6, 128, 3, 4, 16, 1),  # single chunk, g = 3, one head a block
+    (1, 64, 6, 128, 1, 16, 16, 3), # blocks of 3 of 6 heads
 ]
 
 
@@ -104,7 +105,8 @@ def test_ssd_grad_matches_oracle(case, force_head_block):
     from repro.kernels import ssd_chunk_scan
     b, l, h, p, g, n, chunk, *forced = case
     if forced:
-        force_head_block(forced[0], h // g, p, n, chunk, backward=True)
+        force_head_block(forced[0], h // g, p, n, chunk, backward=True,
+                         groups=g)
     rng = np.random.default_rng(abs(hash(case)) % 2**32)
     x = _rand(rng, (b, l, h, p))
     dt = jnp.asarray(rng.uniform(0.01, 0.2, (b, l, h)), jnp.float32)
@@ -116,9 +118,9 @@ def test_ssd_grad_matches_oracle(case, force_head_block):
 
     def fused(x, dt, A, B, C):
         y, st = ssd_chunk_scan(
-            x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
-            B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3), chunk=chunk)
-        return jnp.sum(y.transpose(0, 2, 1, 3) * cy) + jnp.sum(st * cst)
+            x, dt.transpose(0, 2, 1), A, B.transpose(0, 2, 1, 3),
+            C.transpose(0, 2, 1, 3), chunk=chunk)
+        return jnp.sum(y * cy) + jnp.sum(st * cst)
 
     def oracle(x, dt, A, B, C):
         y, st = ssd_scan(x, dt, A, B, C, chunk=chunk)
@@ -152,6 +154,14 @@ def test_per_op_dispatch_rules():
     assert select_ssd_impl("auto", has_initial_state=True) == "xla"
     with pytest.raises(ValueError, match="zero state"):
         select_ssd_impl("pallas", has_initial_state=True)
+
+
+def test_ssd_auto_needs_a_lane_legal_head_block(monkeypatch):
+    """On a TPU, auto takes the kernel only where some head block spans a
+    multiple of 128 lanes (or every head); else the XLA scan."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert select_ssd_impl("auto") == "pallas"
+    assert select_ssd_impl("auto", lanes_ok=False) == "xla"
 
 
 def test_gspmd_kernel_plan_rules(monkeypatch):

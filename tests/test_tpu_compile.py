@@ -70,7 +70,7 @@ def _attention(hq, hkv, hd, s, window=0, softcap=0.0, scale=None):
 def _ssd(h=32, l=4096, p=64, n=128, chunk=128):
     def fwd(x, dt, A, B, C):
         return ssd_chunk_scan(x, dt, A, B, C, chunk=chunk, interpret=False)[0]
-    shapes = [((1, h, l, p), jnp.bfloat16), ((1, h, l), jnp.float32),
+    shapes = [((1, l, h, p), jnp.bfloat16), ((1, h, l), jnp.float32),
               ((h,), jnp.float32), ((1, 1, l, n), jnp.bfloat16),
               ((1, 1, l, n), jnp.bfloat16)]
     return fwd, shapes, (0, 1, 2, 3, 4), ("ssd_fwd", "ssd_bwd")
@@ -91,7 +91,7 @@ def _gemm(e=8, c=512, d=2048, f=1024):
 # (d=2048, f=1024; 8 of its 64 experts); zamba2-7b's chip share, one of two
 # tensor-parallel ranks: 16 attention heads of 224 (not a multiple of the
 # 128 lanes) with its (224/2)^-1/2 scale, and one Mamba-2 group of 56 heads
-# (N=64, chunk 256: head blocks of 28 forward, 14 backward)
+# (N=64, chunk 256: head blocks of 28 in both passes)
 KERNELS = {
     "attention-qwen1.5-4b": lambda: _attention(20, 20, 128, 4096),
     "attention-gemma2-9b": lambda: _attention(16, 8, 256, 4096, window=4096,

@@ -217,10 +217,10 @@ def test_decode_through_the_cache_matches_forward():
 def test_ssd_head_block_at_56_heads():
     """Zamba2-7B's chip share: 56 heads in one group, P 64, N 64, chunk
     256. The rule takes divisors that are not powers of two: 28 heads a
-    grid step forward, 14 backward, each within the VMEM budget."""
+    grid step in both passes, each within the VMEM budget."""
     fwd = ssd_scan.ssd_head_block(56, 64, 64, 256)
     bwd = ssd_scan.ssd_head_block(56, 64, 64, 256, backward=True)
-    assert (fwd, bwd) == (28, 14)
+    assert (fwd, bwd) == (28, 28)
     for hb, backward in ((fwd, False), (bwd, True)):
         assert ssd_scan.vmem_bytes(hb, 64, 64, 256, backward) \
             <= ssd_scan.VMEM_BUDGET
